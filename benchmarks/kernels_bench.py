@@ -1,10 +1,10 @@
 """Kernel micro-benchmarks.
 
-On this CPU host the Pallas kernels run in interpret mode, so wall time is
-NOT a TPU performance signal — ``derived`` therefore reports the semantic
-quality metric (quantization relative error / max deviation vs oracle), and
-the TPU-side performance is covered by the roofline benches (which read the
-compiled dry-run artifacts).
+The kernels run where :func:`repro.kernels.platform.interpret_mode` says:
+Mosaic on a TPU, the Pallas interpreter elsewhere.  An interpreter wall time
+is NOT a TPU performance signal, so every row names its mode, and
+``derived`` reports the semantic quality metric (quantization relative
+error / max deviation vs oracle).
 """
 from __future__ import annotations
 
@@ -18,8 +18,14 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.kernels.platform import interpret_mode  # noqa: E402
+
 # --smoke drops the larger shape per kernel (interpret mode is slow on CPU).
 _SMOKE = False
+
+
+def _mode() -> str:
+    return "interpret" if interpret_mode() else "mosaic"
 
 
 def kernel_npu_matmul():
@@ -31,13 +37,13 @@ def kernel_npu_matmul():
     for m, k, n in shapes:
         x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-        out = ops.npu_matmul(x, w, interpret=True)
+        out = ops.npu_matmul(x, w).block_until_ready()
         t0 = time.perf_counter()
-        out = ops.npu_matmul(x, w, interpret=True)
+        out = ops.npu_matmul(x, w).block_until_ready()
         us = (time.perf_counter() - t0) * 1e6
         exact = x @ w
         rel = float(jnp.linalg.norm(out - exact) / jnp.linalg.norm(exact))
-        rows.append((f"kernel/npu_matmul_{m}x{k}x{n}", us, rel))
+        rows.append((f"kernel/npu_matmul_{m}x{k}x{n} ({_mode()})", us, rel))
     return rows
 
 
@@ -54,13 +60,15 @@ def kernel_flash_attention():
         q = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(b, s, kh, hd)), jnp.float32)
         v = jnp.asarray(rng.normal(size=(b, s, kh, hd)), jnp.float32)
-        out = fk.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128, interpret=True)
+        out = fk.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128,
+                                 interpret=interpret_mode()).block_until_ready()
         t0 = time.perf_counter()
-        out = fk.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128, interpret=True)
+        out = fk.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128,
+                                 interpret=interpret_mode()).block_until_ready()
         us = (time.perf_counter() - t0) * 1e6
         ref = fr.sdpa_ref(q, k, v, causal=True)
         err = float(jnp.max(jnp.abs(out - ref)))
-        rows.append((f"kernel/flash_attn_b{b}s{s}h{h}", us, err))
+        rows.append((f"kernel/flash_attn_b{b}s{s}h{h} ({_mode()})", us, err))
     return rows
 
 

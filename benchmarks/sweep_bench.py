@@ -58,7 +58,7 @@ import jax  # noqa: E402
 from repro.core import PolicySpec  # noqa: E402
 from repro.core import sim_batch, sim_multi_batch, sweep_shard  # noqa: E402
 from repro.core.audit import AUDIT_TOL  # noqa: E402
-from repro.core.compile_cache import CompileCounter  # noqa: E402
+from repro.core.compile_cache import CompileCounter, enable_compile_cache  # noqa: E402
 from repro.session import ScenarioSpec, Session, SweepGrid, TraceSpec  # noqa: E402
 
 N_FRAMES = 120
@@ -66,7 +66,6 @@ POLICIES = (("jax_accuracy", {}), ("jax_utility", {"alpha": 200.0}))
 NET_POLICIES = (("max_accuracy", {}), ("max_utility", {"alpha": 200.0}))
 SIZES = (10, 100, 1000)
 DEFAULT_OUT = "BENCH_sweep.json"
-DEFAULT_CACHE_DIR = ".jax_cache/sweep_bench"
 
 # The scale cell: the paper's offload-capable utility planner on a short
 # clip, streamed.  2.0 ms/point warm on a 1-core host — 100k points is a
@@ -258,7 +257,7 @@ def make_scale_grid(points: int) -> SweepGrid:
     )
 
 
-def bench_scale_cell(points: int, cache_dir: str) -> dict:
+def bench_scale_cell(points: int) -> dict:
     """The streaming + persistent-cache headline (module docstring).
 
     Protocol: spot-check a 16-point corner against the reference loop, then
@@ -284,8 +283,8 @@ def bench_scale_cell(points: int, cache_dir: str) -> dict:
         _stats_equiv(a.stats, b.stats) for a, b in zip(ref.points, bat.points)
     )
 
-    run_kw = dict(backend="batched", chunk_size=SCALE_CHUNK,
-                  keep_points=False, compile_cache=cache_dir)
+    cache_dir = enable_compile_cache()
+    run_kw = dict(backend="batched", chunk_size=SCALE_CHUNK, keep_points=False)
     _clear_compiled()  # the spot check must not pre-warm the cold pass
     with _RssSampler() as rss:
         with CompileCounter() as c1:
@@ -358,19 +357,15 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=DEFAULT_OUT, help=f"output path (default {DEFAULT_OUT})")
     ap.add_argument("--scale-points", type=int, default=None,
                     help="scale-cell grid size (default 10000 smoke / 100000 full; 0 skips it)")
-    ap.add_argument("--cache-dir", default=None,
-                    help=f"persistent compile-cache dir for the scale cell "
-                         f"(default $REPRO_COMPILE_CACHE or {DEFAULT_CACHE_DIR})")
     args = ap.parse_args(argv)
 
     scale_points = args.scale_points
     if scale_points is None:
         scale_points = 10_000 if args.smoke else 100_000
-    cache_dir = args.cache_dir or os.environ.get("REPRO_COMPILE_CACHE") or DEFAULT_CACHE_DIR
 
     result = run(sizes=(10,) if args.smoke else SIZES)
     if scale_points:
-        result["cells"].append(bench_scale_cell(scale_points, cache_dir))
+        result["cells"].append(bench_scale_cell(scale_points))
     with open(args.out, "w") as fh:
         json.dump(result, fh, indent=2)
         fh.write("\n")

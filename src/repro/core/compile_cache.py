@@ -3,13 +3,18 @@
 The sweep engines' planner programs cost seconds to tens of seconds to
 compile and milliseconds to run; at 10^5-point scale the only tolerable
 cold start is one that *loads* executables instead of rebuilding them.
-:func:`enable_compile_cache` points jax's persistent compilation cache at
-a directory (opt-in: ``Session.run_sweep(compile_cache=...)``, the sweep
-CLI's ``--compile-cache``, or the ``REPRO_COMPILE_CACHE`` environment
-variable), with the size/time thresholds zeroed so every planner program
-is cached.  Combined with the bucketing policy (:mod:`.bucketing` — stable
-shapes => byte-identical jaxprs => identical cache keys), a re-run of any
-sweep on a warm directory skips XLA entirely.
+:func:`enable_compile_cache` turns jax's persistent compilation cache on,
+with the size/time thresholds zeroed so every planner program is cached.
+One rule places it: ``JAX_COMPILATION_CACHE_DIR`` when that is set (and
+then no code sets another directory), otherwise the fixed ``.jax_cache/``
+at the checkout root, resolved from this file and not from the working
+directory.  Entry points (``chip_smoke.py``, ``launch/serve.py``, the
+sweep CLI, ``sweep_bench``'s scale cell) enable it before their first
+compile; library calls such as ``Session.run_sweep`` leave it alone.
+Tests place it by setting the environment variable.  Combined with the
+bucketing policy (:mod:`.bucketing` — stable shapes => byte-identical
+jaxprs => identical cache keys), a re-run of any sweep on a warm
+directory skips XLA entirely.
 
 :class:`CompileCounter` counts what actually happened, via
 ``jax.monitoring`` events:
@@ -27,23 +32,27 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
+from pathlib import Path
 
 import jax
 import jax.monitoring
 from jax._src import compilation_cache as _compilation_cache
-from jax._src import monitoring as _monitoring
 
-_ENV_VAR = "REPRO_COMPILE_CACHE"
+_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/core/compile_cache.py -> the checkout root
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
-    """Enable jax's persistent compilation cache at ``cache_dir``.
+def enable_compile_cache() -> str:
+    """Enable jax's persistent compilation cache; returns its directory.
 
-    Idempotent; creates the directory.  Thresholds are zeroed so even
-    fast-compiling programs persist (the default 1s floor would skip the
-    small shape buckets that dominate smoke grids).
+    The directory is ``$JAX_COMPILATION_CACHE_DIR``, read at call time, or
+    :data:`DEFAULT_CACHE_DIR` when that is unset.  Idempotent; creates the
+    directory.  Thresholds are zeroed so even fast-compiling programs
+    persist (the default 1s floor would skip the small shape buckets that
+    dominate smoke grids).
     """
-    path = os.fspath(cache_dir)
+    path = os.environ.get(_ENV_VAR) or str(DEFAULT_CACHE_DIR)
     os.makedirs(path, exist_ok=True)
     changed = jax.config.jax_compilation_cache_dir != path
     jax.config.update("jax_compilation_cache_dir", path)
@@ -56,11 +65,6 @@ def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
     if changed or getattr(_compilation_cache, "_cache", None) is None:
         _compilation_cache.reset_cache()
     return path
-
-
-def default_cache_dir() -> str | None:
-    """The opt-in cache directory from the environment, if any."""
-    return os.environ.get(_ENV_VAR) or None
 
 
 @dataclass
@@ -104,6 +108,6 @@ class CompileCounter:
 
     def __exit__(self, *exc) -> None:
         on_event, on_duration = self._handles
-        _monitoring._unregister_event_listener_by_callback(on_event)
-        _monitoring._unregister_event_duration_listener_by_callback(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
         self._handles = []

@@ -350,7 +350,7 @@ def local_utility_dp_jax(
 # policies accumulate their DPs in float64 (numpy arrays / Python floats),
 # so the network-aware batched planners (core/sim_batch) cannot reuse the
 # f32 kernels above without drifting on ties.  These twins pin f64 — they
-# must be traced inside ``jax.experimental.enable_x64`` — and keep every
+# must be traced inside ``jax.enable_x64`` — and keep every
 # sequential tie-break of the reference updates (first model wins ties,
 # case A beats case B within a model, stable (t, -u) candidate order).
 # ---------------------------------------------------------------------------

@@ -21,9 +21,9 @@ same order.  Three mechanisms make that possible:
     arrival times, windows, f32 casts of policy params) is precomputed here
     with the identical numpy expressions;
   * the only round-coupled quantity, ``npu_free``, is carried on device in
-    float64 — the module runs its programs inside ``jax.experimental
-    .enable_x64`` and the DP kernels pin their own dtypes so the f32
-    recurrences do not silently widen;
+    float64 — the module runs its programs inside ``jax.enable_x64`` and
+    the DP kernels pin their own dtypes so the f32 recurrences do not
+    silently widen;
   * fixed shapes come from *padding*, never truncation: windows pad to the
     batch-max frame count ``W`` (padded frames are identity no-ops in the
     kernels) and the Max-Accuracy time grid pads to the batch-max bin count
@@ -64,7 +64,6 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .audit import AUDIT_TOL
 from .bucketing import quant_bins as _quant_bins
@@ -390,7 +389,7 @@ def _run_accuracy(models, scenarios, strict):
             dur_f = np.ceil(c.t_npu64[None, :] / grid[:, None])
         dur = np.where(np.isfinite(dur_f), np.minimum(dur_f, NBINS), NBINS).astype(np.int32)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _accuracy_program(c.W, NBINS, c.J, strict)(
                 c.gamma, c.deadline, grid, c.n_active, nbins_real, c.n_frames,
                 arr_bins, dl_bins, dur, c.arrivals, c.acc_stat64,
@@ -481,7 +480,7 @@ def _run_utility(models, scenarios, strict):
         w32 = window.astype(np.float32)
         t_npu32 = c.t_npu64.astype(np.float32)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _utility_program(c.W, width, c.J, strict)(
                 c.gamma, c.deadline, c.n_active, c.n_frames,
                 g32, d32, a32, w32, c.arrivals, c.acc_stat64,
@@ -553,12 +552,51 @@ def segment_arrays(
     return bw_t, bw_v, S
 
 
-def _net_arrays(group: list[BatchScenario]) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """Per-scenario network tensors: rtt [B] plus the padded segment
-    tensors of :func:`segment_arrays`."""
+def segment_heads(bw_t: np.ndarray, gamma: np.ndarray) -> np.ndarray:
+    """[B, S] int32: for each trace segment, the first round head ``h`` whose
+    start time ``h * gamma`` — the reference's float64 product — reaches
+    the segment's start; sentinel (``+inf``) segments never start.
+
+    Decided on the host in IEEE float64, so on device the segment in force
+    at a round is an integer comparison of ``head`` against these.  A
+    device float compare would put a boundary that falls on a frame time
+    (0.3 s at 30 fps) on either side, by the last bit of ``head * gamma``
+    — and a TPU's emulated float64 does not round that product as IEEE."""
+    g = np.asarray(gamma, np.float64)[:, None]
+    fin = np.isfinite(bw_t)
+    with np.errstate(invalid="ignore"):
+        h = np.where(fin, np.maximum(np.ceil(bw_t / g), 0.0), 0.0)
+    while True:  # ceil(t / gamma) is within an ulp-step of the product's answer
+        down = fin & (h > 0) & ((h - 1.0) * g >= bw_t)
+        up = fin & (h * g < bw_t)
+        if not (down.any() or up.any()):
+            break
+        h = h - down + up
+    return np.where(fin, h, np.iinfo(np.int32).max).astype(np.int32)
+
+
+def _net_arrays(
+    group: list[BatchScenario], gamma: np.ndarray, nbits8: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """Per-scenario network tensors: rtt [B], the first head of each trace
+    segment [B, S] (:func:`segment_heads`), and each segment's upload time
+    per offered resolution [B, S, R] — ``nbits / bandwidth``, the
+    reference's ``upload_time``, divided on the host in IEEE float64."""
     bw_t, bw_v, S = segment_arrays([s.bw_segments for s in group])
     rtt = np.array([s.rtt for s in group], np.float64)
-    return rtt, bw_t, bw_v, S
+    bw = bw_v[:, :, None]
+    with np.errstate(divide="ignore"):
+        t_up = np.where(bw > 0.0, nbits8[:, None, :] / np.where(bw > 0.0, bw, 1.0), np.inf)
+    return rtt, segment_heads(bw_t, gamma), t_up, S
+
+
+def _upload_at_head(seg_head: jax.Array, t_up_seg: jax.Array, head: jax.Array) -> jax.Array:
+    """Upload times [R] under the trace segment in force at round ``head``:
+    the reference's ``trace.at(head * gamma).upload_time(...)``, found by
+    integer head (the last segment whose first head is <= ``head``; before
+    the first segment's start the first applies)."""
+    idx = jnp.searchsorted(seg_head, head, side="right") - 1
+    return t_up_seg[jnp.clip(idx, 0, seg_head.shape[0] - 1)]
 
 
 def _offload_tables(
@@ -589,7 +627,7 @@ def _net_group_key(s: BatchScenario) -> tuple[int, int]:
 def _max_accuracy_program(W: int, NBINS: int, S: int, J: int, R: int, strict: bool):
     def one(gamma, deadline, rtt, grid, n_active, n_frames,
             arr0, dl0, arr1, dl1, dur, arrivals, acc_stat,
-            nbits8, acc_sv, bw_t, bw_v, t_srv, acc_dp, t_npu64):
+            acc_sv, seg_head, t_up_seg, t_srv, acc_dp, t_npu64):
         ks = jnp.arange(W, dtype=jnp.int32)
 
         def cond(c):
@@ -602,8 +640,7 @@ def _max_accuracy_program(W: int, NBINS: int, S: int, J: int, R: int, strict: bo
             t0 = _no_fma(head.astype(jnp.float64) * gamma, rounded)
             npu_free = jnp.maximum(0.0, busy - t0)
             start_bin = jnp.ceil(jnp.maximum(npu_free, 0.0) / grid).astype(jnp.int32)
-            bw0 = _trace_bw(bw_t, bw_v, t0)  # the reference's trace.at(t0)
-            t_up = jnp.where(bw0 > 0.0, nbits8 / bw0, jnp.inf)  # [R]
+            t_up = _upload_at_head(seg_head, t_up_seg, head)  # [R] at trace.at(t0)
             budget = deadline - t_up - rtt  # [R]
             fits = t_srv[:, None] <= budget[None, :]  # [J, R]
             a_cand = jnp.where(fits, acc_sv, -jnp.inf)
@@ -710,7 +747,7 @@ def _max_accuracy_program(W: int, NBINS: int, S: int, J: int, R: int, strict: bo
         out = jax.lax.while_loop(cond, body, init)
         return out[2], out[3], out[4], out[6], out[7], out[5]
 
-    return LaneProgram(one, (0,) * 17 + (None,) * 3)
+    return LaneProgram(one, (0,) * 16 + (None,) * 3)
 
 
 @_planner("max_accuracy")
@@ -737,14 +774,14 @@ def _run_max_accuracy(models, scenarios, strict):
         with np.errstate(invalid="ignore"):
             dur_f = np.ceil(c.t_npu64[None, :] / grid[:, None])
         dur = np.where(np.isfinite(dur_f), np.minimum(dur_f, NBINS), NBINS).astype(np.int32)
-        rtt, bw_t, bw_v, S = _net_arrays(group)
         nbits8, acc_sv = _offload_tables(models, group)
+        rtt, seg_head, t_up_seg, S = _net_arrays(group, c.gamma, nbits8)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _max_accuracy_program(c.W, NBINS, S, c.J, R, strict)(
                 c.gamma, c.deadline, rtt, grid, c.n_active, c.n_frames,
                 arr0, dl0, arr1, dl1, dur, c.arrivals, c.acc_stat64,
-                nbits8, acc_sv, bw_t, bw_v, t_srv, acc_dp, c.t_npu64,
+                acc_sv, seg_head, t_up_seg, t_srv, acc_dp, c.t_npu64,
             )
             out = [np.asarray(a) for a in out]
         return _collect(c, out[:5], time.perf_counter() - t0, offloaded=out[5])
@@ -769,7 +806,7 @@ def _run_max_accuracy(models, scenarios, strict):
 @lru_cache(maxsize=None)
 def _track_program(S: int, J: int, R: int, KQ: int, A: int, strict: bool, fixed: bool):
     def one(gamma, deadline, rtt, n_frames, k_lim, im, ret_pow,
-            acc_stat, nbits8, acc_sv, bw_t, bw_v, t_srv, t_npu64):
+            acc_stat, acc_sv, seg_head, t_up_seg, t_srv, t_npu64):
         def cond(c):
             return c[0] < n_frames
 
@@ -785,8 +822,7 @@ def _track_program(S: int, J: int, R: int, KQ: int, A: int, strict: bool, fixed:
             k_npu = jnp.maximum(kf.astype(jnp.int32), 1)  # [J] npu_interval
             feas_npu = local & (npu_free + t_npu64 <= deadline) & (k_npu <= k_lim)
             # Offload candidates: the reference's _server_candidates, r asc.
-            bw0 = _trace_bw(bw_t, bw_v, t0)
-            t_up = jnp.where(bw0 > 0.0, nbits8 / bw0, jnp.inf)  # [R]
+            t_up = _upload_at_head(seg_head, t_up_seg, head)  # [R]
             budget = deadline - t_up - rtt  # [R]
             fits = t_srv[:, None] <= budget[None, :]  # [J, R]
             a_cand = jnp.where(fits, acc_sv, -jnp.inf)
@@ -862,7 +898,7 @@ def _track_program(S: int, J: int, R: int, KQ: int, A: int, strict: bool, fixed:
         out = jax.lax.while_loop(cond, body, init)
         return out[4], out[5], out[6], out[8], out[9], out[7]
 
-    return LaneProgram(one, (0,) * 12 + (None,) * 2)
+    return LaneProgram(one, (0,) * 11 + (None,) * 2)
 
 
 def _run_track(models, scenarios, strict, *, fixed: bool):
@@ -890,13 +926,13 @@ def _run_track(models, scenarios, strict, *, fixed: bool):
         ret_pow = np.empty((B, A), np.float64)
         for i, s in enumerate(group):
             ret_pow[i, :] = retention_powers(s.workload.retention, A)
-        rtt, bw_t, bw_v, S = _net_arrays(group)
         nbits8, acc_sv = _offload_tables(models, group)
+        rtt, seg_head, t_up_seg, S = _net_arrays(group, c.gamma, nbits8)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _track_program(S, c.J, R, KQ, A, strict, fixed)(
                 c.gamma, c.deadline, rtt, c.n_frames, k_lim, im, ret_pow,
-                c.acc_stat64, nbits8, acc_sv, bw_t, bw_v, t_srv, c.t_npu64,
+                c.acc_stat64, acc_sv, seg_head, t_up_seg, t_srv, c.t_npu64,
             )
             out = [np.asarray(a) for a in out]
         return _collect(c, out[:5], time.perf_counter() - t0, offloaded=out[5])
@@ -917,7 +953,7 @@ def _run_track_fixed(models, scenarios, strict):
 @lru_cache(maxsize=None)
 def _max_utility_program(W: int, S: int, J: int, R: int, strict: bool, width: int):
     def one(gamma, deadline, rtt, alpha, fps, n_w, n_frames, arrivals, acc_stat,
-            nbits8, acc_sv, bw_t, bw_v, t_srv, acc_dp, t_npu64):
+            acc_sv, seg_head, t_up_seg, t_srv, acc_dp, t_npu64):
         ks = jnp.arange(W, dtype=jnp.int32)
 
         def backtrack(u_final, parents, actions):
@@ -958,8 +994,7 @@ def _max_utility_program(W: int, S: int, J: int, R: int, strict: bool, width: in
             rounded = n_frames > 0  # traced, always true: _no_fma's gate
             t0 = _no_fma(head.astype(jnp.float64) * gamma, rounded)
             npu_free = jnp.maximum(0.0, busy - t0)
-            bw0 = _trace_bw(bw_t, bw_v, t0)
-            t_up = jnp.where(bw0 > 0.0, nbits8 / bw0, jnp.inf)  # [R]
+            t_up = _upload_at_head(seg_head, t_up_seg, head)  # [R]
             # Offload phase: argmax_{j,r} capped-rate + alpha * a(j, r); the
             # reference iterates r-outer/j-inner with strict >, so the first
             # maximum over the r-major flattening wins ties identically.
@@ -1051,7 +1086,7 @@ def _max_utility_program(W: int, S: int, J: int, R: int, strict: bool, width: in
         out = jax.lax.while_loop(cond, body, init)
         return out[2], out[3], out[4], out[6], out[7], out[5], out[8]
 
-    return LaneProgram(one, (0,) * 13 + (None,) * 3)
+    return LaneProgram(one, (0,) * 12 + (None,) * 3)
 
 
 @_planner("max_utility")
@@ -1066,12 +1101,12 @@ def _run_max_utility(models, scenarios, strict):
         c = _common(models, group, W)
         alpha = np.array([float(s.params["alpha"]) for s in group], np.float64)
         fps = np.array([s.stream.fps for s in group], np.float64)
-        rtt, bw_t, bw_v, S = _net_arrays(group)
         nbits8, acc_sv = _offload_tables(models, group)
+        rtt, seg_head, t_up_seg, S = _net_arrays(group, c.gamma, nbits8)
         lane_args = (c.gamma, c.deadline, rtt, alpha, fps, c.n_active, c.n_frames,
-                     c.arrivals, c.acc_stat64, nbits8, acc_sv, bw_t, bw_v)
+                     c.arrivals, c.acc_stat64, acc_sv, seg_head, t_up_seg)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _max_utility_program(c.W, S, c.J, R, strict, _UTIL_FAST_WIDTH)(
                 *lane_args, t_srv, acc_dp, c.t_npu64,
             )

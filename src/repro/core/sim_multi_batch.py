@@ -87,7 +87,6 @@ from typing import Any, Callable, Mapping, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .jax_sched import (
     NEG,
@@ -1355,7 +1354,7 @@ def _run_offload(models, scenarios, strict):
 
         program = _fleet_program(alloc, N, K, F, len(models), R, S)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = program(
                 bw_t, bw_v, gamma, T, rtt, fps, L, alpha, is_util, w_fluid,
                 w_eff, tot_w, prio, order, bits_r, acc_sv, t_srv,
@@ -1451,7 +1450,7 @@ def _run_max_accuracy_fleet(models, scenarios, strict):
         program = _acc_fleet_program(alloc, N, K, F, c.W, NBINS, S, c.J,
                                      len(resolutions), strict)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = program(
                 bw_t, bw_v, c.gamma, c.deadline, rtt, grid, L, c.n_active,
                 arr0, dl0, arr1, dl1, dur, c.arrivals, c.acc_stat64,
@@ -1493,7 +1492,7 @@ def _run_max_utility_fleet(models, scenarios, strict):
         shared = (bits_r, acc_sv, t_srv, acc_dp, c.t_npu64)
 
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _util_fleet_program(
                 alloc, N, K, F, c.W, S, c.J, len(resolutions), strict,
                 _UTIL_FAST_WIDTH,
@@ -1594,7 +1593,7 @@ def _run_jax_accuracy_fleet(models, scenarios, strict):
         dur = np.where(np.isfinite(dur_f), np.minimum(dur_f, NBINS), NBINS).astype(np.int32)
         ncl, den0, gated, L, bw_t, bw_v, S = _jax_fleet_lane_arrays(group)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _jax_acc_fleet_program(c.W, NBINS, S, c.J, strict)(
                 c.gamma, c.deadline, grid, c.n_active, nbins_real, c.n_frames,
                 arr_bins, dl_bins, dur, c.arrivals, c.acc_stat64,
@@ -1624,7 +1623,7 @@ def _run_jax_utility_fleet(models, scenarios, strict):
         t_npu32 = c.t_npu64.astype(np.float32)
         ncl, den0, gated, L, bw_t, bw_v, S = _jax_fleet_lane_arrays(group)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _jax_util_fleet_program(c.W, width, S, c.J, strict)(
                 c.gamma, c.deadline, c.n_active, c.n_frames,
                 g32, d32, a32, w32, c.arrivals, c.acc_stat64,
@@ -1881,7 +1880,7 @@ def _run_track_fleet(models, scenarios, strict, *, fixed: bool):
 
         program = _track_fleet_program(alloc, N, K, F, KQ, S, c.J, R, fixed)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = program(
                 bw_t, bw_v, c.gamma, c.deadline, rtt, L, k_lim, im, ret_pow,
                 c.acc_stat64, w_fluid, w_eff, tot_w, prio, order,

@@ -48,7 +48,6 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .audit import AUDIT_TOL
 from .bucketing import quant_bins as _quant_bins
@@ -66,7 +65,7 @@ from .sim_batch import (
     _net_group_key,
     _offload_tables,
     _stitch,
-    _trace_bw,
+    _upload_at_head,
 )
 from .sweep_shard import LaneProgram
 
@@ -96,35 +95,9 @@ class OnlineScenario:
     pessimism: float = 0.9
 
 
-def _install_barrier_batching() -> bool:
-    """``jax.lax.optimization_barrier`` ships without a vmap batching rule on
-    this JAX version; the barrier is elementwise-identity, so the rule is the
-    trivial one.  Registered once, guarded so a future JAX that provides its
-    own rule wins."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _rule(args, dims):
-            return optimization_barrier_p.bind(*args), dims
-
-        batching.primitive_batchers[optimization_barrier_p] = _rule
-    return True
-
-
-_HAS_BARRIER = _install_barrier_batching()
-
-
 def _barrier(x):
-    """Identity that XLA must not optimize across (see ``_true_offload``).
-    Falls back to a traced multiply-gate if the barrier primitive is ever
-    unavailable — weaker (XLA may still reassociate), but never wrong by
-    more than the reference's own double-rounding ulp."""
-    if _HAS_BARRIER:
-        return jax.lax.optimization_barrier(x)
-    return x * jnp.where(x < jnp.inf, 1.0, 1.0)  # pragma: no cover
+    """Identity that XLA must not optimize across (see ``_true_offload``)."""
+    return jax.lax.optimization_barrier(x)
 
 
 _ONLINE: dict[str, Callable[..., list[tuple[StreamStats, dict]]]] = {}
@@ -213,11 +186,10 @@ def _with_meta(stats: list[StreamStats], bps_final, pess) -> list[tuple[StreamSt
 # ---------------------------------------------------------------------------
 
 
-def _true_offload(*, active, use_off, r_off, j_off, t0, deadline, rtt, beta, omb,
+def _true_offload(*, active, use_off, r_off, j_off, head, t0, deadline, rtt, beta, omb,
                   bps, rttb, netf, acc_sum, proc, miss, offl,
-                  nbits8, acc_sv, bw_t, bw_v, t_srv, rounded, rounded2):
-    bw_true = _trace_bw(bw_t, bw_v, t0)  # the reference's trace.at(t0)
-    tup_t = jnp.where(bw_true > 0.0, nbits8[r_off] / bw_true, jnp.inf)
+                  nbits8, acc_sv, seg_head, t_up_seg, t_srv, rounded, rounded2):
+    tup_t = _upload_at_head(seg_head, t_up_seg, head)[r_off]  # the reference's trace.at(t0)
     start = jnp.maximum(netf, t0)  # d.start == 0.0 for both planners' heads
     fin = ((start + tup_t) + rtt) + t_srv[j_off]
     ok = fin <= (t0 + deadline) + AUDIT_TOL  # true completion: never strict-gated
@@ -271,7 +243,7 @@ def _true_offload(*, active, use_off, r_off, j_off, t0, deadline, rtt, beta, omb
 def _online_accuracy_program(W: int, NBINS: int, S: int, J: int, R: int, strict: bool):
     def one(gamma, deadline, rtt, grid, beta, omb, pess, bps0, n_active, n_frames,
             arr0, dl0, arr1, dl1, dur, arrivals, acc_stat,
-            nbits8, acc_sv, bw_t, bw_v, t_srv, acc_dp, t_npu64):
+            nbits8, acc_sv, seg_head, t_up_seg, t_srv, acc_dp, t_npu64):
         ks = jnp.arange(W, dtype=jnp.int32)
 
         def cond(c):
@@ -350,10 +322,10 @@ def _online_accuracy_program(W: int, NBINS: int, S: int, J: int, R: int, strict:
             # SERVER first, then the NPU frames of the audit fold).
             bps, rttb, netf, acc_sum, proc, miss, offl = _true_offload(
                 active=active, use_off=use_off, r_off=r_star, j_off=j_best[r_star],
-                t0=t0, deadline=deadline, rtt=rtt, beta=beta, omb=omb,
+                head=head, t0=t0, deadline=deadline, rtt=rtt, beta=beta, omb=omb,
                 bps=bps, rttb=rttb, netf=netf, acc_sum=acc_sum, proc=proc,
                 miss=miss, offl=offl, nbits8=nbits8, acc_sv=acc_sv,
-                bw_t=bw_t, bw_v=bw_v, t_srv=t_srv, rounded=rounded,
+                seg_head=seg_head, t_up_seg=t_up_seg, t_srv=t_srv, rounded=rounded,
                 rounded2=rounded2,
             )
 
@@ -411,15 +383,15 @@ def _run_online_max_accuracy(models, scenarios, strict):
         with np.errstate(invalid="ignore"):
             dur_f = np.ceil(c.t_npu64[None, :] / grid[:, None])
         dur = np.where(np.isfinite(dur_f), np.minimum(dur_f, NBINS), NBINS).astype(np.int32)
-        rtt, bw_t, bw_v, S = _net_arrays(group)
         nbits8, acc_sv = _offload_tables(models, group)
+        rtt, seg_head, t_up_seg, S = _net_arrays(group, c.gamma, nbits8)
         beta, omb, pess, bps0 = _estimator_arrays(group)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _online_accuracy_program(c.W, NBINS, S, c.J, R, strict)(
                 c.gamma, c.deadline, rtt, grid, beta, omb, pess, bps0,
                 c.n_active, c.n_frames, arr0, dl0, arr1, dl1, dur,
-                c.arrivals, c.acc_stat64, nbits8, acc_sv, bw_t, bw_v,
+                c.arrivals, c.acc_stat64, nbits8, acc_sv, seg_head, t_up_seg,
                 t_srv, acc_dp, c.t_npu64,
             )
             out = [np.asarray(a) for a in out]
@@ -438,7 +410,7 @@ def _run_online_max_accuracy(models, scenarios, strict):
 @lru_cache(maxsize=None)
 def _online_utility_program(W: int, S: int, J: int, R: int, strict: bool, width: int):
     def one(gamma, deadline, rtt, alpha, fps, beta, omb, pess, bps0, n_w, n_frames,
-            arrivals, acc_stat, nbits8, acc_sv, bw_t, bw_v, t_srv, acc_dp, t_npu64):
+            arrivals, acc_stat, nbits8, acc_sv, seg_head, t_up_seg, t_srv, acc_dp, t_npu64):
         ks = jnp.arange(W, dtype=jnp.int32)
 
         def backtrack(u_final, parents, actions):
@@ -531,10 +503,10 @@ def _online_utility_program(W: int, S: int, J: int, R: int, strict: bool, width:
 
             bps, rttb, netf, acc_sum, proc, miss, offl = _true_offload(
                 active=active, use_off=use_off, r_off=r0, j_off=jnp.clip(j0, 0, J - 1),
-                t0=t0, deadline=deadline, rtt=rtt, beta=beta, omb=omb,
+                head=head, t0=t0, deadline=deadline, rtt=rtt, beta=beta, omb=omb,
                 bps=bps, rttb=rttb, netf=netf, acc_sum=acc_sum, proc=proc,
                 miss=miss, offl=offl, nbits8=nbits8, acc_sv=acc_sv,
-                bw_t=bw_t, bw_v=bw_v, t_srv=t_srv, rounded=rounded,
+                seg_head=seg_head, t_up_seg=t_up_seg, t_srv=t_srv, rounded=rounded,
                 rounded2=rounded2,
             )
 
@@ -584,14 +556,14 @@ def _run_online_max_utility(models, scenarios, strict):
         c = _common(models, group, W)
         alpha = np.array([float(s.params["alpha"]) for s in group], np.float64)
         fps = np.array([s.stream.fps for s in group], np.float64)
-        rtt, bw_t, bw_v, S = _net_arrays(group)
         nbits8, acc_sv = _offload_tables(models, group)
+        rtt, seg_head, t_up_seg, S = _net_arrays(group, c.gamma, nbits8)
         beta, omb, pess, bps0 = _estimator_arrays(group)
         lane_args = (c.gamma, c.deadline, rtt, alpha, fps, beta, omb, pess, bps0,
                      c.n_active, c.n_frames, c.arrivals, c.acc_stat64,
-                     nbits8, acc_sv, bw_t, bw_v)
+                     nbits8, acc_sv, seg_head, t_up_seg)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _online_utility_program(c.W, S, c.J, R, strict, _UTIL_FAST_WIDTH)(
                 *lane_args, t_srv, acc_dp, c.t_npu64,
             )

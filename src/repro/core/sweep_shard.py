@@ -25,7 +25,7 @@ shards.
 
 Scenario lane buffers are deliberately **not** donated.  A planner never
 reads a lane argument after the call, so donation looks free — but on
-this jax (0.4.37/CPU) an executable compiled with ``donate_argnums`` and
+jax 0.4.37 on the CPU an executable compiled with ``donate_argnums`` and
 *reloaded from the persistent compilation cache* returns corrupted stats
 for a nondeterministic subset of lanes (reproduced and bisected to
 donation by the scale bench: clean with donation off, hundreds of zeroed
@@ -39,7 +39,6 @@ from functools import lru_cache
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -89,9 +88,9 @@ def _sharded_jit(prog: LaneProgram, mesh: Mesh):
     lane = rules._resolve((mesh.size,), ("scenario",))
     assert lane != P(), "sweep mesh must expose a scenario/batch axis"
     in_specs = tuple(lane if i < prog.n_lane else P() for i in range(prog.n_args))
-    sm = shard_map(
+    sm = jax.shard_map(
         prog._vmapped, mesh=mesh, in_specs=in_specs,
-        out_specs=lane, check_rep=False,
+        out_specs=lane, check_vma=False,
     )
     return jax.jit(sm)
 

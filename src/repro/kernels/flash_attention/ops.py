@@ -1,28 +1,15 @@
 """Jit'd public wrapper for flash attention.
 
-``attention(q, k, v, causal=...)`` dispatches: Pallas Mosaic kernel on TPU,
-interpret-mode kernel when REPRO_INTERPRET_KERNELS=1 (CPU validation), else
-the blockwise jnp fallback (what the models use in SPMD dry-runs).
+``attention(q, k, v, causal=...)`` runs the Pallas kernel: Mosaic on a TPU,
+the Pallas interpreter elsewhere (:func:`repro.kernels.platform.interpret_mode`).
 """
 from __future__ import annotations
 
-import os
-
-import jax
-
-from ...models.layers import blockwise_sdpa
+from ..platform import interpret_mode
 from . import kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def attention(q, k, v, *, causal: bool = True, block_q: int = 256, block_kv: int = 512):
-    if _on_tpu():
-        return kernel.flash_attention(q, k, v, causal=causal, block_q=block_q, block_kv=block_kv)
-    if os.environ.get("REPRO_INTERPRET_KERNELS") == "1":
-        return kernel.flash_attention(
-            q, k, v, causal=causal, block_q=block_q, block_kv=block_kv, interpret=True
-        )
-    return blockwise_sdpa(q, k, v, causal=causal, q_block=block_q, kv_block=block_kv)
+    return kernel.flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret_mode()
+    )

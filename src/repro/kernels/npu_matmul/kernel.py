@@ -6,8 +6,10 @@ matmuls through this kernel.  TPU-native design (not a CUDA port):
 
   * grid (M/bm, N/bn, K/bk); K innermost so each (i, j) tile accumulates in a
     VMEM int32 scratch across K steps — MXU-friendly int8 x int8 -> int32.
-  * BlockSpecs tile x [bm, bk], w [bk, bn], out [bm, bn]; scales are tiny
-    per-row/col vectors blocked along the same grid axes.
+  * BlockSpecs tile x [bm, bk], w [bk, bn], out [bm, bn]; the scales ride
+    as 2-D [M, 1] / [1, N] columns/rows blocked (bm, 1) / (1, bn) along the
+    same grid axes, so the epilogue broadcasts them with no 1-D -> 2-D
+    relayout (Mosaic refuses 1-D f32 blocks whose tiling differs from XLA's).
   * The f32 rescale happens ONCE, on the last K step, fused in-kernel
     (dequant epilogue) — no extra HBM round-trip for the int32 accumulator.
 
@@ -42,7 +44,7 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _epilogue():
-        scale = xs_ref[...][:, None] * ws_ref[...][None, :]
+        scale = xs_ref[...] * ws_ref[...]  # [bm, 1] * [1, bn] -> [bm, bn]
         out_ref[...] = (acc_ref[...].astype(jnp.float32) * scale).astype(out_ref.dtype)
 
 
@@ -77,11 +79,11 @@ def int8_matmul(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(x_q, w_q, x_scale, w_scale)
+    )(x_q, w_q, x_scale.reshape(M, 1), w_scale.reshape(1, N))

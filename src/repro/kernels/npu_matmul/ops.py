@@ -4,20 +4,16 @@
 weights) and runs the Pallas kernel; ``npu_matmul_prequant`` takes already
 quantized weights (the serving path: weights are quantized once at load).
 
-On non-TPU backends the kernel runs in interpret mode (the kernel body
-executed by the Pallas interpreter) so CPU tests validate the real kernel
-logic; on TPU it compiles to Mosaic.
+``interpret=None`` follows :func:`repro.kernels.platform.interpret_mode`:
+Mosaic on a TPU, the Pallas interpreter everywhere else.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from . import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x, m, axis):
@@ -40,8 +36,6 @@ def npu_matmul(
     x: jax.Array, w: jax.Array, *, out_dtype=jnp.float32, interpret: bool | None = None
 ) -> jax.Array:
     """[..., K] x [K, N] -> [..., N] through int8 quantization (both sides)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     xq, xs = ref.quantize_rowwise(x2)
@@ -62,8 +56,7 @@ def npu_matmul_prequant(
     block_n: int = 128,
     block_k: int = 512,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     M, K = x_q.shape
     N = w_q.shape[1]
     # Adaptive block sizes: small matmuls (the serving single-frame case —

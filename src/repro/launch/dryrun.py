@@ -93,8 +93,7 @@ def run_cell(
     from ..models.layers import flash_accounting
 
     t0 = time.time()
-    # jax.set_mesh is newer than 0.4.x; Mesh itself is a context manager.
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         jitted = prog.jit()
         abstract = prog.abstract_args()
         lowered = jitted.lower(*abstract)

@@ -11,12 +11,7 @@ from jax.sharding import Mesh
 
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; on older jax the Auto axis
-    # type is simply the (only) default, so omit the kwarg there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -134,8 +134,10 @@ def main(argv: list[str] | None = None) -> dict:
     args = ap.parse_args(argv)
 
     from ..core import StreamSpec
+    from ..core.compile_cache import enable_compile_cache
     from ..core.registry import get_policy
 
+    enable_compile_cache()
     needs_alpha = any(p.name == "alpha" and p.required for p in get_policy(args.policy).params)
     spec = ScenarioSpec(
         policy=PolicySpec(args.policy, {"alpha": args.alpha} if needs_alpha else {}),

@@ -47,20 +47,33 @@ def fake_quant_tree(params: Any, *, min_ndim: int = 2) -> Any:
     return jax.tree.map(q, params)
 
 
+@jax.jit
+def _leaf_errors(params: Any, qparams: Any) -> list:
+    """Per leaf: ``(changed, relative L2 error)`` for a floating leaf whose
+    shape survived quantization, ``None`` for every other leaf — one
+    compiled pass and one transfer, where a leaf-by-leaf loop pays a
+    dispatch (and on an accelerator a compile) per small op."""
+    out = []
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(qparams)):
+        if not jnp.issubdtype(a.dtype, jnp.floating) or a.shape != b.shape:
+            out.append(None)
+            continue
+        denom = jnp.linalg.norm(a.astype(jnp.float32))
+        err = jnp.linalg.norm((a - b).astype(jnp.float32))
+        out.append((jnp.any(a != b), err / jnp.where(denom > 0, denom, 1.0)))
+    return out
+
+
 def quant_error_stats(params: Any, qparams: Any) -> QuantStats:
     stats = QuantStats()
     rels = []
-    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(qparams)):
-        if not jnp.issubdtype(a.dtype, jnp.floating):
-            stats.leaves_kept += 1  # int/bool leaves pass through unquantized
-            continue
-        if a.shape == b.shape and bool(jnp.any(a != b)):
-            denom = float(jnp.linalg.norm(a.astype(jnp.float32))) or 1.0
-            rel = float(jnp.linalg.norm((a - b).astype(jnp.float32))) / denom
-            rels.append(rel)
-            stats.leaves_quantized += 1
-        else:
+    # int/bool leaves, and leaves quantization left unchanged, count as kept
+    for leaf in jax.device_get(_leaf_errors(params, qparams)):
+        if leaf is None or not leaf[0]:
             stats.leaves_kept += 1
+            continue
+        rels.append(float(leaf[1]))
+        stats.leaves_quantized += 1
     if rels:
         stats.mean_rel_err = sum(rels) / len(rels)
         stats.max_rel_err = max(rels)
@@ -69,7 +82,7 @@ def quant_error_stats(params: Any, qparams: Any) -> QuantStats:
 
 def npu_variant(params: Any) -> tuple[Any, QuantStats]:
     """The deployable NPU-path weights: int8 fake-quant + stats."""
-    q = fake_quant_tree(params)
+    q = jax.jit(fake_quant_tree)(params)
     return q, quant_error_stats(params, q)
 
 

@@ -182,6 +182,7 @@ def calibrate_model(name: str, cfg: CalibrationConfig) -> CalibratedModel:
 
     from .. import quant
     from ..core.profiles import PAPER_RESOLUTIONS
+    from ..kernels.platform import interpret_mode
     from .engine import ModelEndpoint, degrade_frame, make_synthetic_video
 
     steps = cfg.train_steps.get(name, 150)
@@ -238,8 +239,9 @@ def calibrate_model(name: str, cfg: CalibrationConfig) -> CalibratedModel:
         "provenance": {
             "source": "measured",
             "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
             "kernel": "kernels/npu_matmul"
-            + (" (interpret)" if cfg.interpret or jax.default_backend() != "tpu" else " (mosaic)"),
+            + (" (interpret)" if interpret_mode(cfg.interpret) else " (mosaic)"),
             "train_steps": steps,
             "final_loss": final_loss,
             "t_npu_ms_by_batch": {b: t * 1e3 for b, t in t_npu_by_b.items()},
@@ -280,6 +282,7 @@ def calibrate(cfg: CalibrationConfig | None = None) -> Calibration:
             "r_ref": cfg.r_ref,
         },
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "models": [m.payload for m in models],
     }
     return Calibration(models=models, artifact=artifact)

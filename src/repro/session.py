@@ -45,7 +45,7 @@ from typing import Any, Iterator, Mapping, Sequence
 
 from .core import sim_batch, sim_multi_batch, sim_online_batch
 from .core.audit import AUDIT_TOL, apply_round, audit_round
-from .core.compile_cache import default_cache_dir, enable_compile_cache
+from .core.compile_cache import enable_compile_cache
 from .core.controller import BandwidthEstimator, OnlineController
 from .core.edge_server import ALLOCATION_POLICIES, EdgeServerScheduler, make_fleet
 from .core.profiles import PAPER_MODELS, ModelProfile, StreamSpec
@@ -875,7 +875,6 @@ class Session:
         mode: str = "auto",
         chunk_size: int | None = None,
         keep_points: bool = True,
-        compile_cache: str | None = None,
     ) -> SweepReport:
         """Run the base scenario across every point of ``grid``.
 
@@ -906,9 +905,10 @@ class Session:
         * ``keep_points=False`` — drop per-point results after folding them
           into the summary, so a 10^5–10^6-point grid never lands on the
           host at once.
-        * ``compile_cache`` — enable jax's persistent compilation cache at
-          this directory (defaults to ``$REPRO_COMPILE_CACHE`` when set),
-          so re-runs load planner executables instead of recompiling.
+
+        The persistent compilation cache is the caller's: the sweep CLI
+        turns it on (``core/compile_cache.enable_compile_cache``), so CLI
+        re-runs load planner executables instead of recompiling.
 
         ``mode="online"`` sweeps the observe->replan->execute world of
         ``run_online`` instead of the oracle-bandwidth simulator: each grid
@@ -927,9 +927,6 @@ class Session:
             raise ValueError(f"unknown sweep mode {mode!r}; want one of {self.SWEEP_MODES}")
         if chunk_size is not None and int(chunk_size) < 1:
             raise ValueError(f"chunk_size must be a positive int, got {chunk_size!r}")
-        cache_dir = compile_cache if compile_cache is not None else default_cache_dir()
-        if cache_dir:
-            enable_compile_cache(cache_dir)
         entry = get_policy(self.spec.policy.name)
         n_points = len(grid)
         chunk = n_points if chunk_size is None else int(chunk_size)
@@ -948,8 +945,6 @@ class Session:
         meta: dict[str, Any] = {"requested_backend": backend, "grid_points": n_points}
         if mode != "auto":
             meta["mode"] = mode
-        if cache_dir:
-            meta["compile_cache"] = str(cache_dir)
         streaming = chunk_size is not None or not keep_points
         summary = SweepSummary() if streaming else None
         out_points: list[SweepPoint] = []
@@ -1225,9 +1220,6 @@ def _sweep_main(argv: Sequence[str]) -> int:
     ap.add_argument("--summary-only", action="store_true",
                     help="drop per-point stats, keep only the streaming summary "
                     "(for 10^5+-point grids)")
-    ap.add_argument("--compile-cache", metavar="DIR",
-                    help="persist compiled programs under DIR (jax persistent "
-                    "compilation cache; re-runs skip XLA)")
     ap.add_argument("--example-grid", action="store_true",
                     help="print an example grid JSON and exit")
     args = ap.parse_args(argv)
@@ -1240,14 +1232,15 @@ def _sweep_main(argv: Sequence[str]) -> int:
     try:
         spec = ScenarioSpec.from_json(_read(args.spec))
         grid = SweepGrid.from_json(_read(args.grid))
+        cache_dir = enable_compile_cache()
         report = Session(spec).run_sweep(
             grid,
             backend=args.backend,
             mode=args.mode,
             chunk_size=args.chunk_size,
             keep_points=not args.summary_only,
-            compile_cache=args.compile_cache,
         )
+        report.meta["compile_cache"] = cache_dir
         payload = json.dumps(report.to_json(), indent=2)
         if args.out:
             with open(args.out, "w") as fh:

@@ -70,3 +70,31 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip_slow)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache: entry points place it by one rule
+# ($JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache/), so a test
+# that drives one points the variable at its own directory and puts jax's
+# cache config back afterwards.
+# ---------------------------------------------------------------------------
+
+_CACHE_KEYS = (
+    "jax_compilation_cache_dir",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+)
+
+
+@pytest.fixture
+def compile_cache_dir(tmp_path, monkeypatch):
+    import jax
+    from jax._src import compilation_cache
+
+    path = tmp_path / "jax-cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+    saved = {k: getattr(jax.config, k) for k in _CACHE_KEYS}
+    yield path
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()

@@ -85,6 +85,55 @@ def test_int8_prequant_single_row_golden():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("blocks", ["adaptive", "mosaic_minima"])
+@pytest.mark.parametrize("k,n", [(147, 64), (300, 200)])
+@pytest.mark.parametrize("m", [1, 33, 128 + 5])
+def test_int8_scale_layout_golden(m, k, n, blocks):
+    """The scales ride as [M, 1] / [1, N] blocks.  Every row and column gets
+    its own scale, so a transposed or misaligned scale block shows; K and N
+    are on no block multiple.  ``mosaic_minima`` runs the tiles the TPU path
+    uses (bm >= 32, bk >= 128, bn >= 128) through the interpreter."""
+    from repro.kernels.npu_matmul import kernel as nk
+
+    rng = np.random.default_rng(m * 7 + k + n)
+    xq = jnp.asarray(rng.integers(-127, 128, size=(m, k)), jnp.int8)
+    wq = jnp.asarray(rng.integers(-127, 128, size=(k, n)), jnp.int8)
+    xs = jnp.asarray(rng.uniform(0.5, 2.0, size=m), jnp.float32)
+    ws = jnp.asarray(rng.uniform(0.5, 2.0, size=n), jnp.float32)
+    ref = nref.int8_matmul_ref(xq, wq, xs, ws)
+    if blocks == "adaptive":
+        out = nops.npu_matmul_prequant(xq, xs, wq, ws, interpret=True)
+    else:
+        bm, bk, bn = 32, 128, 128
+        out = nk.int8_matmul(
+            nops._pad_to(nops._pad_to(xq, bm, 0), bk, 1),
+            nops._pad_to(nops._pad_to(wq, bk, 0), bn, 1),
+            nops._pad_to(xs, bm, 0), nops._pad_to(ws, bn, 0),
+            block_m=bm, block_n=bn, block_k=bk, interpret=True,
+        )[:m, :n]
+    assert out.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
+
+
+def test_interpret_rule_shared_by_both_kernels():
+    """One rule for both kernels: interpret exactly off a TPU — so on the
+    CPU both run their kernel body in the interpreter (flash attention no
+    longer falls back to the blockwise jnp path)."""
+    from repro.kernels.flash_attention import ops as fops
+    from repro.kernels.platform import interpret_mode, pallas_interpret_flags
+
+    assert jax.default_backend() != "tpu"
+    assert interpret_mode() is True
+    assert interpret_mode(False) is False
+    x, w = jnp.ones((4, 16)), jnp.ones((16, 8))
+    assert pallas_interpret_flags(nops.npu_matmul, x, w) == [True]
+    q = jnp.ones((1, 64, 4, 32))
+    kv = jnp.ones((1, 64, 2, 32))
+    attn = lambda q, k, v: fops.attention(q, k, v, block_q=32, block_kv=32)  # noqa: E731
+    flags = pallas_interpret_flags(attn, q, kv, kv)
+    assert flags and all(flags)
+
+
 def test_quant_error_stats_counts_mixed_tree():
     """Non-float leaves (step counters, bool masks) must count as kept, so
     leaves_quantized + leaves_kept == total leaves on any params tree."""

@@ -15,10 +15,11 @@ import pytest
 
 pytest.importorskip("hypothesis", reason="property tests need hypothesis (requirements-dev.txt)")
 import hypothesis.strategies as st  # noqa: E402
-from hypothesis import HealthCheck, given, settings  # noqa: E402
+from hypothesis import HealthCheck, example, given, settings  # noqa: E402
 
 from repro.core import (
     PAPER_MODELS,
+    ModelProfile,
     NetworkState,
     StreamSpec,
     Trace,
@@ -86,7 +87,26 @@ def test_max_utility_plans_feasible(s):
         assert not errors, errors
 
 
+# Counterexamples Hypothesis found on the CPU, pinned so that every run
+# checks them: the whole-run dominance below fails on both (ROADMAP Design 9).
+_CEX_ACCURACY = (
+    [ModelProfile("m0", t_npu=0.005, t_server=0.005,
+                  acc_server={45: 0.25, 134: 0.5, 224: 0.625}, acc_npu={224: 0.3125}),
+     ModelProfile("m1", t_npu=0.031, t_server=0.005,
+                  acc_server={45: 0.2, 134: 0.4, 224: 0.5}, acc_npu={224: 0.5})],
+    StreamSpec(fps=50.0),
+    NetworkState(bandwidth_bps=500000.0, rtt=0.01),
+)
+_CEX_UTILITY = (
+    [ModelProfile("m0", t_npu=0.079, t_server=0.005,
+                  acc_server={45: 0.2, 134: 0.4, 224: 0.5}, acc_npu={224: 0.5625})],
+    StreamSpec(fps=20.0),
+    NetworkState(bandwidth_bps=1000000.0, rtt=0.01),
+)
+
+
 @given(scenario())
+@example(_CEX_ACCURACY)
 @SETTINGS
 def test_max_accuracy_dominates_baselines(s):
     models, stream, net = s
@@ -100,6 +120,7 @@ def test_max_accuracy_dominates_baselines(s):
 
 
 @given(scenario())
+@example(_CEX_UTILITY)
 @SETTINGS
 def test_max_utility_dominates_local(s):
     """Max-Utility contains a Local-equivalent candidate per round, so it can

@@ -257,6 +257,56 @@ def test_npu_forward_routes_model_matmuls_through_kernel():
     assert float(np.max(np.abs(fp - routed))) / denom < 0.25  # round-off, not garbage
 
 
+def _rel_l2(a, ref):
+    return float(np.linalg.norm(np.asarray(a, np.float64) - ref) / np.linalg.norm(ref))
+
+
+@pytest.mark.parametrize("family", ["resnet", "squeezenet"])
+def test_reference_forward_is_float32_and_matches_served_forward(family):
+    """models/reference computes in float32 (it agrees with its own float64
+    run), while the served convnets forward computes in bfloat16: the two
+    differ, by bf16 round-off and no more.  ResNet gets stacked blocks and
+    BatchNorm state away from the identity so every branch is exercised."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.arch import abstract_params as arch_params
+    from repro.arch import classifier_forward
+    from repro.models import convnets
+    from repro.models.common import init_tree
+    from repro.models.reference import reference_logits
+
+    if family == "resnet":
+        arch = dataclasses.replace(
+            configs.get("resnet-50", smoke=True),
+            cfg=convnets.ResNetConfig(name="resnet-stacked", depths=(2, 2), width=8, n_classes=10),
+        )
+    else:
+        arch = configs.get("squeezenet", smoke=True)
+    specs, state_specs = arch_params(arch)
+    rng = np.random.default_rng(5)
+    shift = lambda t, lo, hi: jax.tree.map(  # noqa: E731
+        lambda a: a + jnp.asarray(rng.uniform(lo, hi, a.shape), a.dtype), t)
+    # one compiled init per tree: leaf by leaf, every shape is its own compile
+    params = shift(jax.jit(lambda k: init_tree(k, specs))(jax.random.key(0)), -0.1, 0.1)
+    state = shift(jax.jit(lambda k: init_tree(k, state_specs))(jax.random.key(1)), 0.0, 0.5)
+    x = rng.standard_normal((4, 32, 32, 3)).astype(np.float32)
+
+    served = jax.jit(lambda p, s, x: classifier_forward(arch, p, s, x, train=False)[0])(
+        params, state, x)
+    ref32 = np.asarray(jax.jit(lambda p, s, x: reference_logits(arch, p, s, x))(params, state, x))
+    with jax.enable_x64(True):
+        ref64 = np.asarray(jax.jit(
+            lambda p, s, x: reference_logits(arch, p, s, x, dtype=jnp.float64))(params, state, x))
+    assert ref32.dtype == np.float32 and ref32.shape == served.shape == (4, 10)
+    assert _rel_l2(ref32, ref64) < 1e-5
+    err = _rel_l2(served, ref64)
+    assert 0.0 < err < 5e-2, err
+
+
 # ---------------------------------------------------------------------------
 # Calibration pipeline (heavy: trains + compiles both variants)
 # ---------------------------------------------------------------------------

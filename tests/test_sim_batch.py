@@ -308,16 +308,40 @@ def test_utility_prune_epsilon_window_matches_reference():
         assert abs(got.accuracy_sum - ref.accuracy_sum) <= AUDIT_TOL
 
 
+@pytest.mark.parametrize("fps", [10.0, 15.0, 24.0, 30.0, 60.0])
+def test_segment_heads_match_trace_lookup(fps):
+    """The integer segment lookup picks, at every round head, the segment
+    ``Trace.at(head * gamma)`` picks — including boundaries that fall
+    exactly on a frame time (0.3 s and 0.9 s at 30 fps), where a device
+    float compare is decided by the last bit of ``head * gamma``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.sim_batch import _upload_at_head, segment_arrays, segment_heads
+    from repro.core.simulator import Trace
+
+    points = [(0.0, 3.0), (0.1, 1.0), (0.3, 0.8), (0.31, 0.5), (0.9, 6.0), (2.0, 0.0)]
+    trace = Trace.piecewise(points, rtt_ms=60.0)
+    bw_t, bw_v, _ = segment_arrays([[(t, v * 1e6) for t, v in points]])
+    gamma = 1.0 / fps
+    heads = segment_heads(bw_t, np.array([gamma]))[0]
+    with jax.enable_x64(True):
+        for h in range(int(3.0 * fps)):
+            got = float(_upload_at_head(jnp.asarray(heads), jnp.asarray(bw_v[0]), jnp.int32(h)))
+            assert got == trace.at(h * gamma).bandwidth_bps, (h, got)
+
+
 def test_utility_dp64_overflow_flag():
     """White-box: a width too small for the front sets the overflow flag;
     the reference cap width does not (for this instance)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.jax_sched import _utility_dp64
     from repro.core.profiles import PAPER_MODELS
 
-    with enable_x64():
+    with jax.enable_x64(True):
         t_npu = jnp.array([m.t_npu for m in PAPER_MODELS], jnp.float64)
         acc = jnp.array(
             [m.acc_npu[max(m.acc_npu)] for m in PAPER_MODELS], jnp.float64
@@ -408,7 +432,7 @@ def test_sweep_report_json_round_trip_batched():
     assert rt == rep
 
 
-def test_sweep_cli_smoke(tmp_path, capsys):
+def test_sweep_cli_smoke(tmp_path, capsys, compile_cache_dir):
     from repro.session import main
 
     spec_file = tmp_path / "scenario.json"

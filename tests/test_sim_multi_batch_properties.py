@@ -24,10 +24,10 @@ import pytest
 
 pytest.importorskip("hypothesis", reason="property tests need hypothesis (requirements-dev.txt)")
 import hypothesis.strategies as st  # noqa: E402
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from hypothesis import given, settings  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
 
 from repro.core import (  # noqa: E402
     EdgeServerScheduler,
@@ -37,6 +37,7 @@ from repro.core import (  # noqa: E402
     simulate_multi,
 )
 from repro.core.profiles import StreamSpec, profile_ms  # noqa: E402
+from repro.core.registry import get_policy  # noqa: E402
 from repro.core.sim_multi_batch import (  # noqa: E402
     EQUIV_INT_FIELDS,
     MULTI_TOL,
@@ -98,7 +99,11 @@ def _segments(kind, mbps, rtt_ms, points):
 @st.composite
 def fleet_cases(draw):
     models = draw(model_sets())
-    policy = draw(st.sampled_from(sorted(multi_batched_policies())))
+    # classify planners only: the registry refuses a track planner on the
+    # classify workload these fleets carry
+    policy = draw(st.sampled_from(sorted(
+        p for p in multi_batched_policies() if "classify" in get_policy(p).workloads
+    )))
     if policy in ("max_utility", "jax_utility"):
         params = {"alpha": draw(st.floats(1.0, 400.0))}
     elif policy in ("max_accuracy", "jax_accuracy"):
@@ -188,7 +193,7 @@ def test_waterfill_reservation_never_exceeds_link(n, data, bandwidth):
         ),
         np.float64,
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         phys = _fleet_physics(
             "weighted_fair", n, 2, 4,
             bw_t=jnp.zeros((1,)), bw_v=jnp.full((1,), bandwidth),

@@ -206,27 +206,37 @@ def test_shard_kill_switch_is_identical(monkeypatch):
     _assert_det_equal(on, off)
 
 
-def test_cached_reload_is_identical(tmp_path):
-    """Executables loaded from the persistent compilation cache must score
-    identically to the ones XLA just built.  Regression for the donation
-    hazard documented in core/sweep_shard.py: with ``donate_argnums`` set,
-    cache-reloaded programs returned corrupted lanes."""
+def _drop_executables():
+    """Fresh-process simulation: drop every in-process executable, keep disk."""
     import jax
 
     from repro.core import sim_batch
     from repro.core.sweep_shard import _sharded_jit
 
-    spec, grid = CASES["jax_accuracy"]
-    cache = str(tmp_path / "jax-cache")
-    first = Session(spec).run_sweep(grid, backend="batched", compile_cache=cache)
-    # fresh-process simulation: drop every in-process executable, keep disk
     for name in dir(sim_batch):
         obj = getattr(sim_batch, name)
         if callable(getattr(obj, "cache_clear", None)):
             obj.cache_clear()
     _sharded_jit.cache_clear()
     jax.clear_caches()
-    reloaded = Session(spec).run_sweep(grid, backend="batched", compile_cache=cache)
+
+
+def test_cached_reload_is_identical(compile_cache_dir):
+    """Executables loaded from the persistent compilation cache must score
+    identically to the ones XLA just built.  Regression for the donation
+    hazard documented in core/sweep_shard.py: with ``donate_argnums`` set,
+    cache-reloaded programs returned corrupted lanes."""
+    from repro.core.compile_cache import CompileCounter, enable_compile_cache
+
+    spec, grid = CASES["jax_accuracy"]
+    assert enable_compile_cache() == str(compile_cache_dir)
+    _drop_executables()  # earlier tests in this process must not pre-warm the cold run
+    first = Session(spec).run_sweep(grid, backend="batched")
+    assert any(compile_cache_dir.iterdir()), "the cold run wrote nothing to the cache"
+    _drop_executables()
+    with CompileCounter() as counter:
+        reloaded = Session(spec).run_sweep(grid, backend="batched")
+    assert counter.cache_hits > 0, "the reload compiled instead of loading from the cache"
     _assert_det_equal(first, reloaded)
 
 
@@ -284,7 +294,7 @@ def test_sharded_groups_bit_identical_across_devices():
     assert "SHARD_EQUIV_OK" in out.stdout
 
 
-def test_sweep_cli_chunked_summary(tmp_path, capsys):
+def test_sweep_cli_chunked_summary(tmp_path, capsys, compile_cache_dir):
     from repro.session import main
 
     spec_file = tmp_path / "scenario.json"
@@ -292,15 +302,13 @@ def test_sweep_cli_chunked_summary(tmp_path, capsys):
     spec = ScenarioSpec(policy=PolicySpec("local"), n_frames=6)
     spec_file.write_text(json.dumps(spec.to_json()))
     grid_file.write_text(json.dumps(SweepGrid(bandwidth_mbps=(1.0, 2.5, 4.0)).to_json()))
-    cache_dir = tmp_path / "jax-cache"
     assert main([
         "sweep", str(spec_file), "--grid", str(grid_file),
         "--chunk-size", "2", "--summary-only",
-        "--compile-cache", str(cache_dir),
     ]) == 0
     report = SweepReport.from_json(json.loads(capsys.readouterr().out))
     assert report.points == []
     assert report.meta["chunks"] == 2
     assert report.meta["summary"]["n_points"] == 3
-    assert report.meta["compile_cache"] == str(cache_dir)
-    assert cache_dir.is_dir()
+    assert report.meta["compile_cache"] == str(compile_cache_dir)
+    assert compile_cache_dir.is_dir()

@@ -87,8 +87,7 @@ for name, spec in [
     a = dataclasses.replace(a, shapes=(spec,))
     rules = MeshRules(mesh, train_rules(mesh) if "train" in spec.kind else serve_rules(mesh))
     prog = steps.build_cell(a, spec.name, rules=rules)
-    # jax.set_mesh is newer than 0.4.x; Mesh itself is a context manager.
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         compiled = prog.jit().lower(*prog.abstract_args()).compile()
     mem = compiled.memory_analysis()
     coll = analysis.parse_collectives(compiled.as_text())

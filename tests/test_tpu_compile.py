@@ -1,0 +1,105 @@
+"""Compile the main path's device programs for a described TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a chip described by its topology.  That catches what interpret mode
+cannot — Mosaic refusing a block layout, a tile off the int8 tiling, f64
+ops the chip's compiler rejects — at no chip time.  Nothing here runs.
+
+The topology is described only inside the module-scoped ``topo`` fixture
+(never at import): only one process at a time may load the TPU library, so
+describing it while the module is imported would break collection under
+several test workers.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.kernels.npu_matmul import ops
+
+# (M, K, N) of the int8 GEMMs ResNet-50 runs at its published width: the
+# head at one frame, the stage-1 3x3 im2col conv and the 7x7 stem at batch
+# 16, a stage-4 3x3 conv at one frame, and a shape on no block multiple.
+NPU_SHAPES = [
+    (1, 2048, 1000),
+    (50176, 576, 64),
+    (200704, 147, 64),
+    (4, 4608, 512),
+    (33, 300, 200),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip; keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("m,k,n", NPU_SHAPES)
+def test_npu_matmul_compiles_to_mosaic(one_chip, m, k, n):
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda x, w: ops.npu_matmul(x, w, interpret=False))
+    compiled = fn.lower(x, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_max_accuracy_lane_program_compiles_x64(one_chip, monkeypatch):
+    """One x64 lane program of the batched planner (``max_accuracy``, one
+    small shape group), with the arguments the engine really passes."""
+    from repro.core import PolicySpec, sim_batch
+    from repro.core.profiles import PAPER_MODELS
+
+    captured = []
+    real = sim_batch._max_accuracy_program
+
+    def capture(*key):
+        prog = real(*key)
+
+        def call(*args):
+            captured.append((prog, args))
+            return prog(*args)
+
+        return call
+
+    monkeypatch.setattr(sim_batch, "_max_accuracy_program", capture)
+    scenarios = [
+        sim_batch.BatchScenario(n_frames=8, params=PolicySpec("max_accuracy").resolved,
+                                bw_segments=((0.0, 3e6), (0.3, 0.8e6)))
+        for _ in range(3)
+    ]
+    sim_batch.simulate_batch("max_accuracy", PAPER_MODELS, scenarios)
+    assert len(captured) == 1
+    prog, args = captured[0]
+    with jax.enable_x64(True):
+        structs = [
+            jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype, sharding=one_chip)
+            for a in args
+        ]
+        assert any(s.dtype == np.float64 for s in structs)
+        compiled = prog.jit.lower(*structs).compile()
+    assert compiled.as_text()
