@@ -1,0 +1,9 @@
+"""95th percentile over all the window's scheduled frames of answer time
+minus due time; a scheduled frame never answered counts at the top."""
+from harness.stats import percentile
+
+
+def read(run):
+    if not run.latencies_s:
+        return None
+    return 1e3 * percentile(run.latencies_s, 95)
