@@ -163,8 +163,7 @@ def run_bench(*, smoke: bool = False, seed: int = 0) -> dict:
 
 # ---------------------------------------------------------------------------
 # run.py auto-discovery: summarize the artifact (cheap; the measured run is
-# the --smoke/full entry point below, like the dry-run artifacts feeding
-# roofline_bench).
+# the --smoke/full entry point below).
 # ---------------------------------------------------------------------------
 
 def serving_summary():

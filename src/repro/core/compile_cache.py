@@ -25,8 +25,10 @@ directory skips XLA entirely.
 * ``cache_misses`` / ``cache_hits`` — persistent-cache outcomes (these
   events only fire when the cache is enabled).
 
-``compiles`` resolves the authoritative "XLA really ran" count from
-whichever signals are live, so benches and tests assert on one number.
+``compiles`` is the authoritative "XLA really ran" count: backend builds
+the persistent cache did not serve, so benches and tests assert on one
+number.  :meth:`CompileCounter.see` is the one place that reads the
+events; ``serving/spans`` counts the program's compiles through it.
 """
 from __future__ import annotations
 
@@ -67,39 +69,56 @@ def enable_compile_cache() -> str:
     return path
 
 
+# jax.monitoring event -> the CompileCounter field it counts
+_EVENT_FIELDS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compiles",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+}
+
+
 @dataclass
 class CompileCounter:
-    """Context manager counting compiles/cache traffic within its scope."""
+    """Context manager counting compiles/cache traffic within its scope.
+
+    Outside ``with`` it is a plain tally: :meth:`see` counts events that a
+    listener of the caller's own hands it."""
 
     backend_compiles: int = 0
     cache_misses: int = 0
     cache_hits: int = 0
     cache_requests: int = 0
+    # Executables XLA actually built: backend builds the persistent cache
+    # did not serve.  A build fires its backend event with or without the
+    # cache, on a disk load too; a load's cache-hit event comes first.  The
+    # request event is NOT a liveness signal (jax emits it with the cache
+    # disabled).
+    compiles: int = 0
+    _served: bool = field(default=False, repr=False)
     _handles: list = field(default_factory=list, repr=False)
 
-    @property
-    def compiles(self) -> int:
-        """Executables XLA actually built (not served from the disk cache)."""
-        # With the persistent cache live, misses are authoritative (backend
-        # builds also fire on disk loads); without it the hit/miss events
-        # never fire and every backend build is real.  The request event is
-        # NOT a liveness signal — jax emits it even with the cache disabled.
-        if self.cache_misses or self.cache_hits:
-            return self.cache_misses
-        return self.backend_compiles
+    def see(self, event: str) -> bool:
+        """Count one ``jax.monitoring`` event; True when it marks a compile."""
+        name = _EVENT_FIELDS.get(event)
+        if name is None:
+            return False
+        setattr(self, name, getattr(self, name) + 1)
+        if name == "cache_hits":
+            self._served = True
+        elif name == "backend_compiles":
+            served, self._served = self._served, False
+            if not served:
+                self.compiles += 1
+                return True
+        return False
 
     def __enter__(self) -> "CompileCounter":
         def on_event(event: str, **kw) -> None:
-            if event == "/jax/compilation_cache/cache_misses":
-                self.cache_misses += 1
-            elif event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-            elif event == "/jax/compilation_cache/compile_requests_use_cache":
-                self.cache_requests += 1
+            self.see(event)
 
         def on_duration(event: str, duration: float, **kw) -> None:
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.backend_compiles += 1
+            self.see(event)
 
         jax.monitoring.register_event_listener(on_event)
         jax.monitoring.register_event_duration_secs_listener(on_duration)

@@ -86,4 +86,6 @@ def int8_matmul(
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        # the kernel's instruction name in a TPU trace, whatever jit wraps it
+        name="int8_matmul",
     )(x_q, w_q, x_scale.reshape(M, 1), w_scale.reshape(1, N))

@@ -11,7 +11,6 @@ from .engine import (  # noqa: F401
     BatchedEndpoint,
     BatchStats,
     EdgeBatchServer,
-    EndpointStats,
     FrameResult,
     ModelEndpoint,
     OffloadRequest,

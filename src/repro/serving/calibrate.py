@@ -23,7 +23,7 @@ already-trained, already-jitted variants) and a JSON artifact whose
 
 Per-batch-size latency tables, fp32/int8 top-1 agreement, and quantization
 error stats ride along under each model's ``"provenance"`` key (ignored by
-the ScenarioSpec loader, consumed by benchmarks/roofline_bench.py).
+the ScenarioSpec loader).
 """
 from __future__ import annotations
 
@@ -196,7 +196,8 @@ def calibrate_model(name: str, cfg: CalibrationConfig) -> CalibratedModel:
     # kernel; the weights it multiplies are the int8 fake-quant values —
     # re-quantizing them is idempotent, so kernel int8s == deployed int8s.
     npu_fwd = quant.npu_forward(forward, interpret=cfg.interpret)
-    edge = ModelEndpoint(f"{name}-edge", lambda x, p=params: forward(p, x), profile_latency_s=0)
+    edge = ModelEndpoint(f"{name}-edge", lambda x, p=params: forward(p, x), profile_latency_s=0,
+                         layer="edge")
     npu = ModelEndpoint(f"{name}-npu", lambda x, p=qparams, f=npu_fwd: f(p, x), profile_latency_s=0)
 
     # -- latency: per serving bucket size, warmup then median ---------------

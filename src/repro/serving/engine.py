@@ -2,7 +2,7 @@
 
 Pieces:
   ModelEndpoint        a jitted classifier forward (full-precision "edge"
-                       variant or int8 "NPU" variant) with measured latency.
+                       variant or int8 "NPU" variant).
   BatchedEndpoint      the multi-tenant variant: pads request batches to a
                        small set of power-of-two bucket sizes so every batch
                        shape hits an already-compiled jitted forward.
@@ -21,6 +21,12 @@ Time model: inference latency and network transfer advance a virtual clock
 (deterministic, testable); the actual numerics come from executing the jitted
 models on this host.  On a TPU estate the same code runs with wall-clock
 timing — the controller only sees (bytes, seconds) either way.
+
+What the host and the device really spend is recorded as spans
+(``serving/spans``): ``round``, ``plan``, ``npu.put``, ``npu.dispatch``,
+``npu.sync``, ``offload.degrade``, ``edge.flush`` and the edge forward's
+``edge.put``, ``edge.dispatch``, ``edge.sync``.  The uplink has no span: it
+lives on the virtual clock and does no host or device work.
 """
 from __future__ import annotations
 
@@ -35,30 +41,29 @@ import numpy as np
 from ..core import OnlineController, StreamSpec
 from ..core.profiles import ModelProfile, NetworkState
 from ..core.schedule import Where
-
-
-@dataclasses.dataclass
-class EndpointStats:
-    calls: int = 0
-    total_s: float = 0.0
+from .spans import RECORDER
 
 
 class ModelEndpoint:
-    """A deployed model variant; forward: (images [B,H,W,3]) -> logits."""
+    """A deployed model variant; forward: (images [B,H,W,3]) -> logits.
+
+    Each call records ``<layer>.dispatch`` (the forward until it returns)
+    and ``<layer>.sync`` (the wait for its result and the copy to the
+    host); ``layer`` is ``"npu"`` or ``"edge"``, the path it serves."""
 
     def __init__(self, name: str, forward: Callable[[jax.Array], jax.Array], *,
-                 profile_latency_s: float):
+                 profile_latency_s: float, layer: str = "npu"):
         self.name = name
         self.forward = jax.jit(forward)
         self.profile_latency_s = profile_latency_s
-        self.stats = EndpointStats()
+        self.recorder = RECORDER
+        self._dispatch, self._sync = f"{layer}.dispatch", f"{layer}.sync"
 
     def __call__(self, images: jax.Array) -> np.ndarray:
-        t0 = time.perf_counter()
-        out = np.asarray(self.forward(images))
-        self.stats.calls += 1
-        self.stats.total_s += time.perf_counter() - t0
-        return out
+        with self.recorder.span(self._dispatch):
+            out = self.forward(images)
+        with self.recorder.span(self._sync):
+            return np.asarray(out)
 
     def warmup(self, images: jax.Array) -> None:
         self.forward(images).block_until_ready()
@@ -69,7 +74,6 @@ class BatchStats:
     flushes: int = 0
     frames: int = 0
     padded: int = 0  # wasted rows added to reach a bucket size
-    total_s: float = 0.0
 
     @property
     def mean_batch(self) -> float:
@@ -87,7 +91,9 @@ class BatchedEndpoint:
     Batches are padded up to the next bucket size (powers of two up to
     ``max_batch``) so the jitted forward compiles once per bucket instead of
     once per observed batch size; the pad rows are sliced off the output.
-    Oversized batches are split into ``max_batch`` chunks.
+    Oversized batches are split into ``max_batch`` chunks.  Each chunk
+    records ``edge.put`` (pad and copy to the device), ``edge.dispatch``
+    and ``edge.sync``.
     """
 
     def __init__(
@@ -109,6 +115,7 @@ class BatchedEndpoint:
             b for b in (1, 2, 4, 8, 16, 32, 64, 128, 256) if b < self.max_batch
         ) + (self.max_batch,)
         self.stats = BatchStats()
+        self.recorder = RECORDER
 
     def _bucket(self, n: int) -> int:
         for b in self.buckets:
@@ -122,18 +129,22 @@ class BatchedEndpoint:
             # The output feature shape is unknowable without running the
             # model, so an empty batch cannot return a consistent array.
             raise ValueError(f"{self.name}: empty batch (need B >= 1)")
-        t0 = time.perf_counter()
+        rec = self.recorder
         outs = []
         for lo in range(0, len(images), self.max_batch):
             chunk = images[lo : lo + self.max_batch]
             b = self._bucket(len(chunk))
             pad = b - len(chunk)
-            x = jnp.asarray(
-                np.concatenate([chunk, np.zeros((pad, *chunk.shape[1:]), chunk.dtype)])
-                if pad
-                else chunk
-            )
-            out = np.asarray(self.forward(x))
+            with rec.span("edge.put"):
+                x = jnp.asarray(
+                    np.concatenate([chunk, np.zeros((pad, *chunk.shape[1:]), chunk.dtype)])
+                    if pad
+                    else chunk
+                )
+            with rec.span("edge.dispatch"):
+                out = self.forward(x)
+            with rec.span("edge.sync"):
+                out = np.asarray(out)
             outs.append(out[: len(chunk)])
             self.stats.padded += pad
             # One flush per FORWARD, not per __call__: an oversized batch
@@ -142,7 +153,6 @@ class BatchedEndpoint:
             # the batching-efficiency stats the serving bench reports.
             self.stats.flushes += 1
         self.stats.frames += len(images)
-        self.stats.total_s += time.perf_counter() - t0
         return np.concatenate(outs)
 
     def warmup(self, sample: np.ndarray) -> None:
@@ -176,6 +186,7 @@ class EdgeBatchServer:
     def __init__(self, endpoints: dict[int, BatchedEndpoint]):
         self.endpoints = endpoints
         self.queue: list[OffloadRequest] = []
+        self.recorder = RECORDER
 
     def submit(self, req: OffloadRequest) -> None:
         if req.model not in self.endpoints:
@@ -186,19 +197,21 @@ class EdgeBatchServer:
         return len(self.queue)
 
     def flush(self) -> dict[tuple[int, int], np.ndarray]:
-        by_model: dict[int, list[OffloadRequest]] = {}
-        for req in self.queue:
-            by_model.setdefault(req.model, []).append(req)
-        results: dict[tuple[int, int], np.ndarray] = {}
-        for model, reqs in by_model.items():
-            batch = np.stack([r.image for r in reqs])
-            logits = self.endpoints[model](batch)
-            for r, row in zip(reqs, logits):
-                results[(r.client_id, r.frame_id)] = row
-        # Clear only after every forward succeeded, so a mid-flush failure
-        # leaves the queue intact for retry instead of dropping requests.
-        self.queue = []
-        return results
+        """Answers to the queue; recorded as an ``edge.flush`` span."""
+        with self.recorder.span("edge.flush"):
+            by_model: dict[int, list[OffloadRequest]] = {}
+            for req in self.queue:
+                by_model.setdefault(req.model, []).append(req)
+            results: dict[tuple[int, int], np.ndarray] = {}
+            for model, reqs in by_model.items():
+                batch = np.stack([r.image for r in reqs])
+                logits = self.endpoints[model](batch)
+                for r, row in zip(reqs, logits):
+                    results[(r.client_id, r.frame_id)] = row
+            # Clear only after every forward succeeded, so a mid-flush failure
+            # leaves the queue intact for retry instead of dropping requests.
+            self.queue = []
+            return results
 
 
 @dataclasses.dataclass
@@ -295,83 +308,102 @@ class VideoServer:
         self.results: list[FrameResult] = []
         self.wall_s = 0.0
         self._net_free_abs = 0.0  # serial true-link occupancy (virtual clock)
+        self.recorder = RECORDER
 
     def run(self, frames: np.ndarray, labels: np.ndarray) -> dict:
         gamma, T = self.stream.gamma, self.stream.deadline
         models = self.controller.models
         r_max = self.stream.r_max
+        rec = self.recorder
         n = len(frames)
         head = 0
         wall0 = time.perf_counter()
         while head < n:
-            t0 = head * gamma
-            plan = self.controller.next_plan(head)
-            horizon = max(plan.horizon, 1)
-            deferred: list[tuple[int, str, float, bool]] = []
-            for d in plan.decisions:
-                fi = head + d.frame
-                if fi >= n:
-                    continue
-                if not d.is_processed():
-                    continue
-                prof: ModelProfile = models[d.model]
-                arrival_abs = t0 + d.frame * gamma
-                if d.where is Where.NPU:
-                    logits = self.npu[d.model](jnp.asarray(frames[fi][None]))
-                    pred = int(np.argmax(logits[0]))
-                    # NPU frames never touch the network; planned times are
-                    # profile-measured, so the plan's window is the audit.
-                    met = d.finish <= d.frame * gamma + T + 1e-9
-                    self.results.append(
-                        FrameResult(
-                            frame=fi,
-                            where="npu",
-                            model=prof.name,
-                            correct=pred == int(labels[fi]),
-                            latency_s=prof.t_npu,
-                            deadline_met=met,
+            with rec.span("round", head):
+                t0 = head * gamma
+                with rec.span("plan"):
+                    plan = self.controller.next_plan(head)
+                horizon = max(plan.horizon, 1)
+                deferred: list[tuple[int, str, float, bool]] = []
+                for d in plan.decisions:
+                    fi = head + d.frame
+                    if fi >= n:
+                        continue
+                    if not d.is_processed():
+                        continue
+                    prof: ModelProfile = models[d.model]
+                    arrival_abs = t0 + d.frame * gamma
+                    if d.where is Where.NPU:
+                        frame = frames[fi]
+                        rec.set_request(fi)
+                        try:
+                            with rec.span("npu.put"):
+                                x = jnp.asarray(frame[None])
+                            logits = self.npu[d.model](x)
+                        finally:
+                            rec.set_request(None)
+                        pred = int(np.argmax(logits[0]))
+                        # NPU frames never touch the network; planned times are
+                        # profile-measured, so the plan's window is the audit.
+                        met = d.finish <= d.frame * gamma + T + 1e-9
+                        self.results.append(
+                            FrameResult(
+                                frame=fi,
+                                where="npu",
+                                model=prof.name,
+                                correct=pred == int(labels[fi]),
+                                latency_s=prof.t_npu,
+                                deadline_met=met,
+                            )
                         )
-                    )
-                    continue
-                # Edge path: measure the transfer on the true link.
-                true_net = self._net_at(arrival_abs)
-                nbytes = self.stream.frame_bytes(d.resolution)
-                t_up = true_net.upload_time(nbytes)
-                # The estimator observes the MEASURED upload time.  (The bug
-                # this replaces fed it net.upload_time() of its own belief —
-                # an echo that could never converge to the true link.)
-                self.controller.report_upload(nbytes, t_up)
-                self.controller.report_rtt(true_net.rtt)
-                if not np.isfinite(t_up):  # dead link: the frame never arrives
-                    # (and must not occupy the uplink forever — leave
-                    # _net_free_abs alone so a recovered trace can send)
-                    self.results.append(
-                        FrameResult(fi, "server", prof.name, False, float("inf"), False)
-                    )
-                    continue
-                start = max(self._net_free_abs, t0 + max(d.start, 0.0))
-                finish_abs = start + t_up + true_net.rtt + prof.t_server
-                self._net_free_abs = start + t_up
-                met = finish_abs <= arrival_abs + T + 1e-9
-                latency = max(finish_abs - arrival_abs, 0.0)
-                img = degrade_frame(frames[fi], d.resolution, r_ref=r_max)
-                if self.edge_server is not None:
-                    self.edge_server.submit(OffloadRequest(0, fi, d.model, img))
-                    deferred.append((fi, prof.name, latency, met))
-                else:
-                    logits = self.edge[d.model](jnp.asarray(img[None]))
-                    pred = int(np.argmax(logits[0]))
-                    self.results.append(
-                        FrameResult(fi, "server", prof.name, pred == int(labels[fi]), latency, met)
-                    )
-            if deferred:
-                out = self.edge_server.flush()
-                for fi, model_name, latency, met in deferred:
-                    pred = int(np.argmax(out[(0, fi)]))
-                    self.results.append(
-                        FrameResult(fi, "server", model_name, pred == int(labels[fi]), latency, met)
-                    )
-            head += horizon
+                        continue
+                    # Edge path: measure the transfer on the true link.
+                    true_net = self._net_at(arrival_abs)
+                    nbytes = self.stream.frame_bytes(d.resolution)
+                    t_up = true_net.upload_time(nbytes)
+                    # The estimator observes the MEASURED upload time.  (The bug
+                    # this replaces fed it net.upload_time() of its own belief —
+                    # an echo that could never converge to the true link.)
+                    self.controller.report_upload(nbytes, t_up)
+                    self.controller.report_rtt(true_net.rtt)
+                    if not np.isfinite(t_up):  # dead link: the frame never arrives
+                        # (and must not occupy the uplink forever — leave
+                        # _net_free_abs alone so a recovered trace can send)
+                        self.results.append(
+                            FrameResult(fi, "server", prof.name, False, float("inf"), False)
+                        )
+                        continue
+                    start = max(self._net_free_abs, t0 + max(d.start, 0.0))
+                    finish_abs = start + t_up + true_net.rtt + prof.t_server
+                    self._net_free_abs = start + t_up
+                    met = finish_abs <= arrival_abs + T + 1e-9
+                    latency = max(finish_abs - arrival_abs, 0.0)
+                    frame = frames[fi]
+                    with rec.span("offload.degrade", fi):
+                        img = degrade_frame(frame, d.resolution, r_ref=r_max)
+                    if self.edge_server is not None:
+                        self.edge_server.submit(OffloadRequest(0, fi, d.model, img))
+                        deferred.append((fi, prof.name, latency, met))
+                    else:
+                        rec.set_request(fi)
+                        try:
+                            with rec.span("edge.put"):
+                                x = jnp.asarray(img[None])
+                            logits = self.edge[d.model](x)
+                        finally:
+                            rec.set_request(None)
+                        pred = int(np.argmax(logits[0]))
+                        self.results.append(
+                            FrameResult(fi, "server", prof.name, pred == int(labels[fi]), latency, met)
+                        )
+                if deferred:
+                    out = self.edge_server.flush()
+                    for fi, model_name, latency, met in deferred:
+                        pred = int(np.argmax(out[(0, fi)]))
+                        self.results.append(
+                            FrameResult(fi, "server", model_name, pred == int(labels[fi]), latency, met)
+                        )
+                head += horizon
         self.wall_s = time.perf_counter() - wall0
         return self.summary()
 
@@ -400,7 +432,6 @@ class VideoServer:
                 bs.flushes += ep.stats.flushes
                 bs.frames += ep.stats.frames
                 bs.padded += ep.stats.padded
-                bs.total_s += ep.stats.total_s
             out["batch"] = {
                 "flushes": bs.flushes,
                 "mean_batch": bs.mean_batch,

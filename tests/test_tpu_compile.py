@@ -13,6 +13,7 @@ several test workers.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,38 @@ def test_npu_matmul_compiles_to_mosaic(one_chip, m, k, n):
     fn = jax.jit(lambda x, w: ops.npu_matmul(x, w, interpret=False))
     compiled = fn.lower(x, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# how bench/metrics/npu_matmul_roofline.py finds the kernel in a TPU trace
+KERNEL_NAME = re.compile(r"^int8_matmul(\.\d+)?$")
+CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet"])
+def test_int8_forward_names_its_kernel(one_chip, arch_name):
+    """The int8 forward, built as the serving path builds it, compiles each
+    GEMM to a ``tpu_custom_call`` whose instruction the trace reader can
+    find by name."""
+    from repro import configs, quant
+    from repro.arch import abstract_params, classifier_forward
+    from repro.models.common import ParamSpec
+
+    arch = configs.get(arch_name, smoke=True)
+
+    def forward(p, s, x):
+        return classifier_forward(arch, p, s, x, train=False)[0]
+
+    def struct(p):
+        return jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip)
+
+    params, state = jax.tree.map(struct, abstract_params(arch),
+                                 is_leaf=lambda p: isinstance(p, ParamSpec))
+    x = jax.ShapeDtypeStruct((1, 32, 32, 3), jnp.float32, sharding=one_chip)
+    fwd = jax.jit(quant.npu_forward(forward, interpret=False))
+    text = fwd.lower(params, state, x).compile().as_text()
+    names = [m.group(1) for m in map(CUSTOM_CALL.match, text.splitlines()) if m]
+    assert names
+    assert all(KERNEL_NAME.match(n) for n in names), names
 
 
 def test_max_accuracy_lane_program_compiles_x64(one_chip, monkeypatch):
