@@ -1,8 +1,22 @@
-"""Pallas TPU kernel: w8a8 int8 matmul with per-row/per-channel scales.
+"""Pallas TPU kernels: w8a8 int8 matmul with per-row/per-channel scales.
 
 This is the "NPU path" of FastVA mapped to the TPU: the paper's phone NPU
 runs CNNs in 8/16-bit — here the quantized variant of every model runs its
-matmuls through this kernel.  TPU-native design (not a CUDA port):
+matmuls through these kernels.  TPU-native design (not a CUDA port).
+
+``quantized_matmul`` takes float operands and quantizes both in VMEM:
+
+  * grid (cdiv(M, bm), cdiv(N, bn)) with the whole K in every block, so the
+    per-row and per-column abs-max see all of K and nothing is padded.  Rows
+    and columns of a partial edge block hold stale data, but each row's and
+    column's scale and output depend on that row or column alone, and the
+    out-of-bounds part of the output block is never written back.
+  * the activation block is quantized once per row block (at j == 0) into a
+    VMEM int8 scratch with its [bm, 1] scales and reused across the column
+    blocks, so j runs in order ("arbitrary"); the weight block is quantized
+    at every step (its total work is cdiv(M, bm) * K * N elementwise).
+
+``int8_matmul`` takes operands already quantized:
 
   * grid (M/bm, N/bn, K/bk); K innermost so each (i, j) tile accumulates in a
     VMEM int32 scratch across K steps — MXU-friendly int8 x int8 -> int32.
@@ -25,6 +39,67 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def _quantize(v: jax.Array, axis: int) -> tuple[jax.Array, jax.Array]:
+    """``ref.quantize_rowwise`` (axis 1) / ``quantize_colwise`` (axis 0) on
+    an f32 block, the scale kept 2-D for the epilogue's broadcast."""
+    amax = jnp.max(jnp.abs(v), axis=axis, keepdims=True)
+    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+    return jnp.clip(jnp.round(v / scale), -127, 127).astype(jnp.int8), scale
+
+
+def _fused_kernel(x_ref, w_ref, out_ref, xq_ref, xs_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _quantize_rows():
+        xq_ref[...], xs_ref[...] = _quantize(x_ref[...].astype(jnp.float32), axis=1)
+
+    wq, ws = _quantize(w_ref[...].astype(jnp.float32), axis=0)
+    acc = jax.lax.dot_general(
+        xq_ref[...], wq, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+    )
+    out_ref[...] = (acc.astype(jnp.float32) * (xs_ref[...] * ws)).astype(out_ref.dtype)
+
+
+# The scoped VMEM the fused kernel compiles under: v5e's default scoped limit
+# is 16 MiB of its 128 MiB, below what ops.VMEM_BUDGET lets one step hold.
+VMEM_LIMIT = 48 << 20
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_m", "block_n", "out_dtype", "interpret")
+)
+def quantized_matmul(
+    x: jax.Array,  # [M, K] float
+    w: jax.Array,  # [K, N] float
+    *,
+    block_m: int,
+    block_n: int,
+    out_dtype=jnp.float32,
+    interpret: bool = False,
+) -> jax.Array:
+    M, K = x.shape
+    K2, N = w.shape
+    assert K == K2, (x.shape, w.shape)
+    bm, bn = block_m, block_n
+    return pl.pallas_call(
+        _fused_kernel,
+        grid=(pl.cdiv(M, bm), pl.cdiv(N, bn)),
+        in_specs=[
+            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
+            pl.BlockSpec((K, bn), lambda i, j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, K), jnp.int8), pltpu.VMEM((bm, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=VMEM_LIMIT
+        ),
+        interpret=interpret,
+        # the same instruction name as int8_matmul: the trace reader and the
+        # compile tests find every NPU GEMM by it
+        name="int8_matmul",
+    )(x, w)
 
 
 def _kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *, n_k: int):

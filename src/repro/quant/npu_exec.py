@@ -24,8 +24,8 @@ from ..models import common
 
 
 def npu_dense(x2d: jax.Array, w2d: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    """One NPU-path GEMM: quantize both sides to int8 and run the Pallas
-    kernel (adaptive block sizes keep small serving shapes un-padded)."""
+    """One NPU-path GEMM: both sides quantized to int8 and multiplied in one
+    Pallas call (``npu_ops.npu_matmul`` picks the path from the shape)."""
     return npu_ops.npu_matmul(x2d, w2d, interpret=interpret)
 
 

@@ -41,10 +41,109 @@ def test_int8_matmul_dtypes(dtype):
     x = jnp.asarray(rng.normal(size=(64, 256)), dtype)
     w = jnp.asarray(rng.normal(size=(256, 64)), dtype)
     out = nops.npu_matmul(x, w, interpret=True)
-    ref = nref.npu_matmul_ref(x, w)
+    # under jit, as the kernel's body is: XLA turns ``amax / 127`` into a
+    # multiply by the reciprocal, and bf16 inputs put values on exact
+    # rounding ties, where that last bit of the scale decides the int8 value
+    ref = jax.jit(nref.npu_matmul_ref)(x, w)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=1e-4, atol=1e-3
     )
+
+
+def _quantized_ref(x, w):
+    """The int8 operands and scales ``ref`` gives under jit, their exact
+    int32 product, and the kernel's epilogue on it."""
+    (xq, xs), (wq, ws) = jax.jit(
+        lambda x, w: (nref.quantize_rowwise(x), nref.quantize_colwise(w))
+    )(x, w)
+    acc = jnp.dot(xq.astype(jnp.int32), wq.astype(jnp.int32))
+    return xq, wq, acc.astype(jnp.float32) * (xs[:, None] * ws[None, :])
+
+
+# K x N of the configs' GEMMs (the ResNet-50 stem, a stage-1 and a stage-4
+# 3x3 conv, the SqueezeNet fire squeeze, the head) at one frame, and M x N
+# off every block multiple (two row and two column blocks, both partial).
+FUSED_SHAPES = [(1, 147, 64), (1, 576, 64), (1, 4608, 512), (1, 16, 64), (1, 2048, 1000),
+                (1300, 70, 300)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m,k,n", FUSED_SHAPES)
+def test_fused_quantize_matches_ref(m, k, n, dtype):
+    """One ``quantized_matmul`` call quantizes both operands in VMEM: the
+    output is bit for bit ``ref``'s int8 operands through an exact int32
+    GEMM and the f32 epilogue, and within the two-pass path's tolerances of
+    ``npu_matmul_ref``."""
+    assert nops.fused_blocks(m, k, n, jnp.dtype(dtype).itemsize) is not None
+    rng = np.random.default_rng(m * 31 + k + n)
+    x = jnp.asarray(rng.normal(size=(m, k)), dtype)
+    w = jnp.asarray(rng.normal(size=(k, n)), dtype)
+    nops.PATHS.clear()
+    out = nops.npu_matmul(x, w, interpret=True)
+    assert nops.PATHS == {"fused": 1}
+    assert out.shape == (m, n) and out.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(_quantized_ref(x, w)[2]))
+    tol = dict(rtol=1e-5, atol=1e-4) if dtype == jnp.float32 else dict(rtol=1e-4, atol=1e-3)
+    ref = jax.jit(nref.npu_matmul_ref)(x, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **tol)
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+def test_fused_quantize_int8_values_exact(axis):
+    """The kernel's in-VMEM quantization gives ``ref``'s int8 values and
+    scales, on bf16 inputs whose ratios land on rounding ties."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.npu_matmul import kernel as nk
+
+    rng = np.random.default_rng(axis)
+    v = jnp.asarray(rng.integers(-254, 255, size=(48, 200)) / 8.0, jnp.bfloat16)
+
+    def body(v_ref, q_ref, s_ref):
+        q_ref[...], s_ref[...] = nk._quantize(v_ref[...].astype(jnp.float32), axis)
+
+    s_shape = (48, 1) if axis == 1 else (1, 200)
+    q, s = pl.pallas_call(
+        body, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct(v.shape, jnp.int8),
+                   jax.ShapeDtypeStruct(s_shape, jnp.float32)],
+    )(v)
+    quantize = nref.quantize_rowwise if axis == 1 else nref.quantize_colwise
+    rq, rs = jax.jit(quantize)(v)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(rq))
+    np.testing.assert_array_equal(np.asarray(s).reshape(-1), np.asarray(rs))
+
+
+def test_fused_zero_row_and_column():
+    """An all-zero activation row and weight column take scale 1 and give
+    exact zeros, in partial edge blocks and beside finite neighbours."""
+    rng = np.random.default_rng(3)
+    x = np.asarray(rng.normal(size=(1100, 96)), np.float32)
+    w = np.asarray(rng.normal(size=(96, 270)), np.float32)
+    x[1090] = 0.0
+    w[:, 265] = 0.0
+    out = np.asarray(nops.npu_matmul(jnp.asarray(x), jnp.asarray(w), interpret=True))
+    assert np.all(out[1090] == 0.0) and np.all(out[:, 265] == 0.0)
+    assert np.all(np.isfinite(out))
+    xq, wq, expect = _quantized_ref(jnp.asarray(x), jnp.asarray(w))
+    assert not np.any(np.asarray(xq)[1090]) and not np.any(np.asarray(wq)[:, 265])
+    np.testing.assert_array_equal(out, np.asarray(expect))
+
+
+def test_fused_falls_back_when_blocks_exceed_budget():
+    """A K whose smallest full-K blocks exceed the VMEM budget keeps the
+    two-pass path (XLA quantizes, ``npu_matmul_prequant`` multiplies)."""
+    k = 1 << 20
+    assert nops._fused_vmem_bytes(1, k, 128, 2) > nops.VMEM_BUDGET
+    assert nops.fused_blocks(1, k, 128, 2) is None
+    assert nops.fused_blocks(1, 4608, 512, 2) is not None
+    nops.PATHS.clear()
+    out = jax.eval_shape(
+        lambda x, w: nops.npu_matmul(x, w, interpret=True),
+        jax.ShapeDtypeStruct((1, k), jnp.bfloat16), jax.ShapeDtypeStruct((k, 128), jnp.bfloat16),
+    )
+    assert out.shape == (1, 128)
+    assert nops.PATHS == {"two_pass": 1}
 
 
 @pytest.mark.parametrize(

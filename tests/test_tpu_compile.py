@@ -62,11 +62,14 @@ def one_chip(topo):
 
 @pytest.mark.parametrize("m,k,n", NPU_SHAPES)
 def test_npu_matmul_compiles_to_mosaic(one_chip, m, k, n):
+    """Each shape compiles as the one quantize-in-kernel call."""
     x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=one_chip)
     fn = jax.jit(lambda x, w: ops.npu_matmul(x, w, interpret=False))
+    ops.PATHS.clear()
     compiled = fn.lower(x, w).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ops.PATHS == {"fused": 1}
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 # how bench/metrics/npu_matmul_roofline.py finds the kernel in a TPU trace
@@ -99,6 +102,68 @@ def test_int8_forward_names_its_kernel(one_chip, arch_name):
     names = [m.group(1) for m in map(CUSTOM_CALL.match, text.splitlines()) if m]
     assert names
     assert all(KERNEL_NAME.match(n) for n in names), names
+
+
+def _int8_forward_hlo(one_chip, arch, res):
+    """The optimized HLO of ``arch``'s int8 forward at one ``res`` x ``res``
+    frame, weights as arguments (as the serving benchmark deploys it), the
+    ``pallas_call`` sites its jaxpr holds (a scan body once), and the path
+    tally of that trace."""
+    from repro import quant
+    from repro.arch import abstract_params, classifier_forward
+    from repro.kernels.platform import pallas_interpret_flags
+    from repro.models.common import ParamSpec
+
+    def forward(p, s, x):
+        return classifier_forward(arch, p, s, x, train=False)[0]
+
+    def struct(p):
+        return jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip)
+
+    params, state = jax.tree.map(struct, abstract_params(arch),
+                                 is_leaf=lambda p: isinstance(p, ParamSpec))
+    x = jax.ShapeDtypeStruct((1, res, res, 3), jnp.float32, sharding=one_chip)
+    fwd = quant.npu_forward(forward, interpret=False)
+    ops.PATHS.clear()
+    sites = len(pallas_interpret_flags(fwd, params, state, x))
+    paths = dict(ops.PATHS)
+    return jax.jit(fwd).lower(params, state, x).compile().as_text(), sites, paths
+
+
+def _top_level_and_fused_ops(text):
+    """``(opcode, op_name)`` of each HLO instruction, split into those of
+    the computations a fusion calls and all others (entry, loop bodies)."""
+    fused_names = set(re.findall(r" fusion\(.*?calls=%([\w.-]+)", text))
+    top, fused, into = [], [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            into = fused if head.group(1) in fused_names else top
+            continue
+        op = re.match(r"^\s*(?:ROOT )?%\S+ = .*? ([a-z][a-z0-9-]*)\(", line)
+        if op and into is not None:
+            name = re.search(r'op_name="([^"]*)"', line)
+            into.append((op.group(1), name.group(1) if name else ""))
+    return top, fused
+
+
+@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet"])
+def test_int8_forward_fused_at_published_width(one_chip, arch_name):
+    """At published width (224 x 224, one frame) every GEMM call site of the
+    int8 forward is one fused ``int8_matmul`` call: no two-pass site, no
+    quantize round and no pad left in XLA around the kernels (the only pads
+    are SqueezeNet's fire concatenates, inside fusions)."""
+    from repro import configs
+
+    text, sites, paths = _int8_forward_hlo(one_chip, configs.get(arch_name), 224)
+    names = [m.group(1) for m in map(CUSTOM_CALL.match, text.splitlines()) if m]
+    assert names and all(KERNEL_NAME.match(n) for n in names), names
+    assert len(names) == sites
+    assert paths == {"fused": sites}
+    assert "round-nearest-even" not in text
+    top, fused = _top_level_and_fused_ops(text)
+    assert not [op for op in top if op[0] == "pad"]
+    assert all(n.endswith("/concatenate") for op, n in fused if op == "pad")
 
 
 def test_max_accuracy_lane_program_compiles_x64(one_chip, monkeypatch):
