@@ -35,16 +35,25 @@ def _fake_quant(w: jax.Array) -> jax.Array:
     return (q * scale).astype(w.dtype)
 
 
+# Leaves added to a GEMM's output rather than multiplied in one: Swin's
+# relative-position-bias table (``models/vision.py``), gathered into every
+# window's attention scores.
+ADDENDS = frozenset({"rel_bias"})
+
+
 def fake_quant_tree(params: Any, *, min_ndim: int = 2) -> Any:
     """Quantize every floating leaf with ndim >= min_ndim (weights/embeddings);
-    biases and norm scales stay exact, matching real NPU toolchains."""
+    biases, norm scales and the ``ADDENDS`` stay exact, matching real NPU
+    toolchains."""
 
-    def q(x):
+    def q(path, x):
+        if path and getattr(path[-1], "key", None) in ADDENDS:
+            return x
         if jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= min_ndim:
             return _fake_quant(x)
         return x
 
-    return jax.tree.map(q, params)
+    return jax.tree_util.tree_map_with_path(q, params)
 
 
 @jax.jit

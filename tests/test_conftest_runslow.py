@@ -6,9 +6,24 @@ an explicit falsy set; these tests pin it down.
 """
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
+
 import pytest
 
-from conftest import run_slow_enabled
+
+def _this_conftest():
+    """This directory's ``conftest.py``, loaded by path: ``bench/tests`` has
+    a ``conftest.py`` of its own, and the bare module name ``conftest`` is
+    whichever of the two pytest loaded last."""
+    spec = importlib.util.spec_from_file_location("tests_conftest",
+                                                  Path(__file__).with_name("conftest.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+run_slow_enabled = _this_conftest().run_slow_enabled
 
 
 @pytest.mark.parametrize(

@@ -77,7 +77,7 @@ KERNEL_NAME = re.compile(r"^int8_matmul(\.\d+)?$")
 CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"")
 
 
-@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet"])
+@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet", "swin-b"])
 def test_int8_forward_names_its_kernel(one_chip, arch_name):
     """The int8 forward, built as the serving path builds it, compiles each
     GEMM to a ``tpu_custom_call`` whose instruction the trace reader can
@@ -147,7 +147,7 @@ def _top_level_and_fused_ops(text):
     return top, fused
 
 
-@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet"])
+@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet", "swin-b"])
 def test_int8_forward_fused_at_published_width(one_chip, arch_name):
     """At published width (224 x 224, one frame) every GEMM call site of the
     int8 forward is one fused ``int8_matmul`` call: no two-pass site, no
