@@ -7,6 +7,7 @@ EfficientNet-B7's 55 blocks stays tractable.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 
@@ -40,18 +41,92 @@ def conv(p, x, stride=1, padding="SAME", groups=1):
 def _conv_via_matmul(p, x, stride, padding):
     """im2col lowering: the conv as ONE [B*H'*W', KH*KW*Cin] x [., Cout] GEMM
     through the active matmul backend — how NPUs (and the int8 Pallas path)
-    actually execute convolutions.  Depthwise convs (groups > 1) stay on
-    lax.conv: they are channel-parallel scalar products, not GEMMs."""
+    actually execute convolutions.  The patches are in (kh, kw, cin) order,
+    so the weight is its HWIO array reshaped, with no transpose.  Depthwise
+    convs (groups > 1) stay on lax.conv: they are channel-parallel scalar
+    products, not GEMMs."""
     kh, kw, cin, cout = p.shape
-    w2d = p.astype(x.dtype)
-    if (kh, kw) == (1, 1) and stride == 1:  # pointwise: a matmul over channels
-        return matmul(x, w2d.reshape(cin, cout))
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )  # [B, H', W', Cin*KH*KW] with Cin slowest (lax patch order)
-    w2d = jnp.transpose(w2d, (2, 0, 1, 3)).reshape(cin * kh * kw, cout)
-    return matmul(patches, w2d)
+    w2d = p.reshape(kh * kw * cin, cout).astype(x.dtype)
+    return matmul(im2col(x, kh, kw, stride, padding), w2d)
+
+
+# A patch row is built by the one-hot convolution where the KH*KW views
+# would outnumber its 128-lane tiles more than this many times over: each
+# view of a narrow input is written padded to whole tiles.  Device time
+# outside the int8 kernel on one v5e: the convolution took ResNet-50's
+# 7x7/2 stem from 0.367 to 0.232 ms (24.5 views per tile), while the views
+# took SqueezeNet's 3x3/2 stem from 0.111 to 0.094 ms (9 per tile) and a
+# 3x3 conv of 16 channels at 56 x 56 from 0.019 to 0.008 ms (4.5 per tile).
+VIEWS_PER_TILE = 16
+LANES = 128
+
+# Conv call sites lowered per patch builder ("pointwise",
+# "strided_pointwise", "nonoverlap", "patch_op", "slices"), counted when
+# im2col is traced: a jit traced once, or a scan body, counts once.
+CONV_PATHS: collections.Counter = collections.Counter()
+
+
+def im2col(x, kh, kw, stride, padding):
+    """[B, H, W, Cin] -> [B, H', W', KH*KW*Cin] patches, channel fastest,
+    then kw, then kh: the rows of an HWIO weight reshaped to 2-D.
+
+    The builder follows from the shapes alone:
+    - a 1x1 kernel with no border is the input, subsampled by the stride;
+    - a kernel equal to its stride with no border tiles the input, so the
+      patches are one reshape and one transpose (Swin's 4x4/4 patch
+      embedding: 0.026 ms outside the kernel on a v5e, against 0.093 ms
+      through the one-hot convolution);
+    - where the KH*KW views would be more than ``VIEWS_PER_TILE`` times
+      the 128-lane tiles of a patch row (a narrow input under a wide
+      window: ResNet-50's stem), one convolution against a one-hot filter;
+    - anything else concatenates the KH*KW strided views of the input, its
+      border built from zero rows and columns so that XLA fuses it into the
+      concatenation.
+    """
+    b, h, w, cin = x.shape
+    if isinstance(padding, str):
+        padding = jax.lax.padtype_to_pads((h, w), (kh, kw), (stride, stride), padding)
+    (top, bottom), (left, right) = padding
+    ho = (h + top + bottom - kh) // stride + 1
+    wo = (w + left + right - kw) // stride + 1
+    border = top or bottom or left or right
+    if (kh, kw) == (1, 1) and not border:
+        CONV_PATHS["pointwise" if stride == 1 else "strided_pointwise"] += 1
+        return jax.lax.slice(x, (0, 0, 0, 0), x.shape, (1, stride, stride, 1))
+    if (kh, kw) == (stride, stride) and not border:
+        CONV_PATHS["nonoverlap"] += 1
+        tiles = x[:, : ho * kh, : wo * kw, :].reshape(b, ho, kh, wo, kw, cin)
+        return tiles.transpose(0, 1, 3, 2, 4, 5).reshape(b, ho, wo, kh * kw * cin)
+    k = kh * kw * cin
+    if kh * kw > VIEWS_PER_TILE * -(-k // LANES):
+        CONV_PATHS["patch_op"] += 1
+        one_hot = jnp.eye(k, dtype=x.dtype).reshape(kh, kw, cin, k)
+        return jax.lax.conv_general_dilated(
+            x, one_hot, (stride, stride), padding, dimension_numbers=("NHWC", "HWIO", "NHWC")
+        )
+    CONV_PATHS["slices"] += 1
+    # At least one zero row and column on every side: XLA turns a one-sided
+    # concatenation with zeros into a pad of its own, which it does not fuse.
+    t, l = max(top, 1), max(left, 1)
+    rows, cols = t + h + max(bottom, 1), l + w + max(right, 1)
+    dtype = x.dtype
+
+    def zeros(*shape):
+        return jnp.zeros(shape, dtype)
+
+    x = jnp.concatenate([zeros(b, t, w, cin), x, zeros(b, rows - t - h, w, cin)], axis=1)
+    x = jnp.concatenate([zeros(b, rows, l, cin), x, zeros(b, rows, cols - l - w, cin)], axis=2)
+    views = [
+        jax.lax.slice(
+            x,
+            (0, t - top + i, l - left + j, 0),
+            (b, t - top + i + stride * (ho - 1) + 1, l - left + j + stride * (wo - 1) + 1, cin),
+            (1, stride, stride, 1),
+        )
+        for i in range(kh)
+        for j in range(kw)
+    ]
+    return jnp.concatenate(views, axis=-1)
 
 
 def bn_specs(ch):

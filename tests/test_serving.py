@@ -176,29 +176,135 @@ def test_degrade_frame_loses_information_monotonically():
 # matmul backend hook + im2col conv lowering
 # ---------------------------------------------------------------------------
 
-def test_matmul_backend_conv_equivalence():
-    """conv() through the backend hook (im2col + GEMM) == lax.conv, including
-    the strided-1x1 projection case."""
+def _lax_patch_conv(p, x, stride, padding):
+    """The lowering ``convnets.im2col`` replaced, kept as the reference:
+    patches from ``lax.conv_general_dilated_patches`` in (cin, kh, kw) order
+    and the weight transposed to match."""
+    import jax
     import jax.numpy as jnp
 
+    from repro.models.common import matmul
+
+    kh, kw, cin, cout = p.shape
+    w = p.astype(x.dtype)
+    if (kh, kw) == (1, 1) and stride == 1:
+        return matmul(x, w.reshape(cin, cout))
+    patches = jax.lax.conv_general_dilated_patches(
+        x, (kh, kw), (stride, stride), padding, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    return matmul(patches, jnp.transpose(w, (2, 0, 1, 3)).reshape(cin * kh * kw, cout))
+
+
+# Every conv class of the published configs, and a narrow 7x7 input on
+# each side of the patch-builder rule (49 views over 3 and over 4 lane
+# tiles of the patch row): (kernel, stride, padding, input height and width,
+# input channels, patch builder).
+CONV_CASES = {
+    "3x3_s1_same": (3, 1, "SAME", 9, 16, "slices"),
+    "3x3_s2_same_odd": (3, 2, "SAME", 9, 16, "slices"),
+    "3x3_s2_same_even": (3, 2, "SAME", 8, 16, "slices"),
+    "3x3_s2_cin3": (3, 2, "SAME", 16, 3, "slices"),
+    "7x7_s2_cin3": (7, 2, "SAME", 16, 3, "patch_op"),
+    "7x7_s2_cin7": (7, 2, "SAME", 16, 7, "patch_op"),
+    "7x7_s2_cin8": (7, 2, "SAME", 16, 8, "slices"),
+    "1x1_s1": (1, 1, "SAME", 9, 16, "pointwise"),
+    "1x1_s2": (1, 2, "SAME", 9, 16, "strided_pointwise"),
+    "4x4_s4_valid_cin3": (4, 4, "VALID", 16, 3, "nonoverlap"),
+    "4x4_s4_valid_wide": (4, 4, "VALID", 18, 16, "nonoverlap"),
+}
+
+
+@pytest.mark.parametrize("case", list(CONV_CASES))
+def test_matmul_backend_conv_equivalence(case):
+    """conv() through the backend hook (im2col + GEMM) == lax.conv, by the
+    patch builder its shape selects; and through the int8 kernel it is bit
+    for bit the (cin, kh, kw) lowering it replaced: the kernel quantizes each
+    row and column by its absolute maximum and sums K in int32, so the same
+    permutation of K on both operands changes no output bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import quant
     from repro.models import convnets
     from repro.models.common import matmul_backend
 
+    k, stride, padding, hw, cin, path = CONV_CASES[case]
     rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.standard_normal((2, 9, 9, 4)).astype(np.float32))
-    cases = [
-        (jnp.asarray(rng.standard_normal((3, 3, 4, 8)).astype(np.float32)), 1),
-        (jnp.asarray(rng.standard_normal((3, 3, 4, 8)).astype(np.float32)), 2),
-        (jnp.asarray(rng.standard_normal((1, 1, 4, 8)).astype(np.float32)), 1),
-        (jnp.asarray(rng.standard_normal((1, 1, 4, 8)).astype(np.float32)), 2),  # strided proj
-    ]
-    for p, stride in cases:
-        direct = convnets.conv(p, x, stride=stride)
-        with matmul_backend(lambda a, b: a @ b):
-            routed = convnets.conv(p, x, stride=stride)
-        np.testing.assert_allclose(
-            np.asarray(routed), np.asarray(direct), rtol=1e-5, atol=1e-5
-        )
+    x = jnp.asarray(rng.standard_normal((2, hw, hw, cin)).astype(np.float32))
+    p = jnp.asarray(rng.standard_normal((k, k, cin, 8)).astype(np.float32))
+
+    direct = convnets.conv(p, x, stride=stride, padding=padding)
+    convnets.CONV_PATHS.clear()
+    with matmul_backend(lambda a, b: a @ b):
+        routed = convnets.conv(p, x, stride=stride, padding=padding)
+    assert convnets.CONV_PATHS == {path: 1}
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(direct), rtol=1e-5, atol=1e-5)
+
+    xb = x.astype(jnp.bfloat16)
+    with quant.npu_execution(interpret=True):
+        ours = jax.jit(lambda p, x: convnets.conv(p, x, stride=stride, padding=padding))(p, xb)
+        ref = jax.jit(lambda p, x: _lax_patch_conv(p, x, stride, padding))(p, xb)
+    assert np.array_equal(np.asarray(ours), np.asarray(ref))
+
+
+@pytest.mark.parametrize("arch_name", ["resnet-50", "squeezenet"])
+def test_int8_forward_bitwise_unchanged_by_patch_order(arch_name, monkeypatch):
+    """The SMOKE int8 forward gives bit for bit the logits of the (cin, kh,
+    kw) lax-patch lowering."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs, quant
+    from repro.arch import abstract_params as arch_params
+    from repro.arch import classifier_forward
+    from repro.models import convnets
+    from repro.models.common import init_tree
+
+    arch = configs.get(arch_name, smoke=True)
+    specs, state_specs = arch_params(arch)
+    params = jax.jit(lambda k: init_tree(k, specs))(jax.random.key(0))
+    state = jax.jit(lambda k: init_tree(k, state_specs))(jax.random.key(1))
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((1, 32, 32, 3)).astype(np.float32))
+
+    def logits():
+        fwd = quant.npu_forward(
+            lambda p, s, x: classifier_forward(arch, p, s, x, train=False)[0], interpret=True)
+        return np.asarray(jax.jit(fwd)(params, state, x))
+
+    ours = logits()
+    monkeypatch.setattr(convnets, "_conv_via_matmul", _lax_patch_conv)
+    ref = logits()
+    assert np.all(np.isfinite(ref))
+    assert np.array_equal(ours, ref)
+
+
+# Conv call sites per patch builder at 224 x 224, a scan body once.
+PUBLISHED_CONV_PATHS = {
+    "resnet-50": {"patch_op": 1, "slices": 8, "pointwise": 17, "strided_pointwise": 3},
+    "squeezenet": {"slices": 9, "pointwise": 17},
+    "swin-b": {"nonoverlap": 1},
+}
+
+
+@pytest.mark.parametrize("arch_name", list(PUBLISHED_CONV_PATHS))
+def test_conv_path_tally_at_published_width(arch_name):
+    """Which patch builder each conv of the int8 forward takes at published
+    width, traced (not run) at one 224 x 224 frame."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs, quant
+    from repro.arch import abstract_params as arch_params
+    from repro.arch import classifier_forward
+    from repro.models import convnets
+    from repro.models.common import abstract_tree
+
+    arch = configs.get(arch_name)
+    specs, state_specs = arch_params(arch)
+    fwd = quant.npu_forward(lambda p, s, x: classifier_forward(arch, p, s, x, train=False)[0])
+    convnets.CONV_PATHS.clear()
+    jax.eval_shape(fwd, abstract_tree(specs), abstract_tree(state_specs),
+                   jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32))
+    assert convnets.CONV_PATHS == PUBLISHED_CONV_PATHS[arch_name]
 
 
 def test_matmul_backend_counts_and_restores():

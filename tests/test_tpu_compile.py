@@ -104,9 +104,9 @@ def test_int8_forward_names_its_kernel(one_chip, arch_name):
     assert all(KERNEL_NAME.match(n) for n in names), names
 
 
-def _int8_forward_hlo(one_chip, arch, res):
-    """The optimized HLO of ``arch``'s int8 forward at one ``res`` x ``res``
-    frame, weights as arguments (as the serving benchmark deploys it), the
+def _int8_forward_compiled(one_chip, arch, res):
+    """``arch``'s int8 forward compiled at one ``res`` x ``res`` frame,
+    weights as arguments (as the serving benchmark deploys it), the
     ``pallas_call`` sites its jaxpr holds (a scan body once), and the path
     tally of that trace."""
     from repro import quant
@@ -127,7 +127,13 @@ def _int8_forward_hlo(one_chip, arch, res):
     ops.PATHS.clear()
     sites = len(pallas_interpret_flags(fwd, params, state, x))
     paths = dict(ops.PATHS)
-    return jax.jit(fwd).lower(params, state, x).compile().as_text(), sites, paths
+    return jax.jit(fwd).lower(params, state, x).compile(), sites, paths
+
+
+def _int8_forward_hlo(one_chip, arch, res):
+    """The optimized HLO of ``_int8_forward_compiled``, its sites and tally."""
+    compiled, sites, paths = _int8_forward_compiled(one_chip, arch, res)
+    return compiled.as_text(), sites, paths
 
 
 def _top_level_and_fused_ops(text):
@@ -164,6 +170,61 @@ def test_int8_forward_fused_at_published_width(one_chip, arch_name):
     top, fused = _top_level_and_fused_ops(text)
     assert not [op for op in top if op[0] == "pad"]
     assert all(n.endswith("/concatenate") for op, n in fused if op == "pad")
+
+
+# XLA's bytes accessed by ResNet-50's int8 forward at 224 x 224 (weights as
+# arguments) with the patches built by a one-hot convolution in (cin, kh,
+# kw) order and every conv weight transposed to match, before the patch
+# builder of convnets.im2col
+LAX_PATCH_RESNET50_BYTES = 1_028_341_248
+SHAPE = re.compile(r"^\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([\d,]*)\]")
+
+
+def test_resnet50_int8_im2col_builds_patches_without_convolution(one_chip):
+    """ResNet-50's int8 forward at published width: the only convolution
+    left is the stem's one-hot patch convolution (the one conv whose views
+    would outnumber the lane tiles of its patch row more than
+    ``convnets.VIEWS_PER_TILE`` times), no instruction outside a fusion
+    transposes a conv weight (per-frame weight transposes), and XLA's bytes
+    accessed fall by at least 15% from the lax-patch lowering."""
+    from repro import configs
+    from repro.arch import abstract_params
+    from repro.models import convnets
+    from repro.models.common import ParamSpec
+
+    arch = configs.get("resnet-50")
+    convnets.CONV_PATHS.clear()
+    compiled, _, _ = _int8_forward_compiled(one_chip, arch, 224)
+    assert convnets.CONV_PATHS["patch_op"] == 1
+    text = compiled.as_text()
+    convs = [SHAPE.match(line).group(1) for line in text.splitlines()
+             if re.match(r"^\s*(?:ROOT )?%\S+ = \S+ convolution\(", line)]
+    assert convs == ["1,112,112,147"]  # 7 x 7 x 3 patches of the 224 x 224 frame
+
+    weights = {
+        s.shape[-4:] for s in jax.tree.leaves(abstract_params(arch)[0],
+                                              is_leaf=lambda p: isinstance(p, ParamSpec))
+        if s.init == "conv" and s.shape[-4] * s.shape[-3] > 1
+    }
+    fused_names = set(re.findall(r" fusion\(.*?calls=%([\w.-]+)", text))
+    transposed, top = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            top = head.group(1) not in fused_names
+            continue
+        m = SHAPE.match(line)
+        if not (top and m and m.group(1)):
+            continue
+        assert " transpose(" not in line, line
+        dims = tuple(int(d) for d in m.group(1).split(",") if d != "1")
+        if any(sorted(dims) == sorted(w) and dims != w for w in weights):
+            transposed.append(line.strip()[:120])
+    assert top is not None and not transposed, transposed
+
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= 0.85 * LAX_PATCH_RESNET50_BYTES, cost["bytes accessed"]
 
 
 def test_max_accuracy_lane_program_compiles_x64(one_chip, monkeypatch):
