@@ -11,6 +11,8 @@ including the ``brute_force`` oracle and the jitted ``jax_*`` DPs, which the
 sweep engine routes through the vectorized ``sim_batch`` backend — is just
 another name in a tuple.  One-off cells (fig 7's oracle gap) still use a
 single-point ``ScenarioSpec``.
+
+    PYTHONPATH=src python benchmarks/paper_figures.py   # every row, as CSV
 """
 from __future__ import annotations
 
@@ -227,3 +229,15 @@ ALL = [
     oracle_gap_sweep,
     sched_latency,
 ]
+
+
+def main() -> None:
+    """Print every figure's rows as ``name,us_per_call,derived`` CSV."""
+    print("name,us_per_call,derived")
+    for figure in ALL:
+        for name, us, derived in figure():
+            print(f"{name},{us:.2f},{derived:.6f}", flush=True)
+
+
+if __name__ == "__main__":
+    main()

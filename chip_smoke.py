@@ -284,8 +284,7 @@ def four_chip_phase() -> None:
     """One sweep grid through run_sharded on a four-device scenario mesh
     (5 lanes: padded to 8) against the same grid on one device."""
     from repro.core import PolicySpec
-    from repro.core.sweep_shard import _sharded_jit
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.sweep_shard import _sharded_jit, make_sweep_mesh
     from repro.session import ScenarioSpec, Session, SweepGrid, TraceSpec
 
     check(make_sweep_mesh().size == 4, f"sweep mesh has {make_sweep_mesh().size} devices")

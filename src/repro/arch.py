@@ -1,171 +1,40 @@
-"""Uniform architecture surface: every assigned arch (+ the paper's own
-SqueezeNet) is an ``Arch`` with a family adapter providing abstract params,
-state, and per-shape step functions.  configs/<id>.py files instantiate these;
-launch/, tests/, and benchmarks/ consume only this API.
+"""Uniform architecture surface: each served classifier (ResNet-50,
+SqueezeNet, Swin-B) is an ``Arch`` whose family names its adapter, the
+abstract params and the forward.  ``configs/<id>.py`` files instantiate
+these; serving, calibration, tests and the benchmark consume only this API.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Any
 
-import jax
-import jax.numpy as jnp
-
-from .models import convnets, diffusion, lm, vision
-from .models.common import ParamSpec, param_count, spec
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str  # train | prefill | decode | denoise_train | denoise_step | classify_train | classify_serve
-    batch: int
-    seq: int = 0  # LM sequence / KV-cache length
-    img: int = 0  # image resolution (pixel space)
-    steps: int = 0  # sampler steps (documentation; one step is lowered)
+from .models import convnets, vision
 
 
 @dataclasses.dataclass(frozen=True)
 class Arch:
     name: str
-    family: str  # lm | dit | flux | vit | swin | resnet | effnet | squeezenet
+    family: str  # resnet | squeezenet | swin
     cfg: Any
-    shapes: tuple[ShapeSpec, ...]
     notes: str = ""
-    # Per-arch overrides merged into the mesh sharding rule table (e.g. flux:
-    # 24 heads don't divide the 16-way model axis, so head_dim sharding only
-    # forces qkv re-gathers — replicate attention weights instead).
-    sharding_overrides: dict | None = None
-
-    def shape(self, name: str) -> ShapeSpec:
-        for s in self.shapes:
-            if s.name == name:
-                return s
-        raise KeyError(f"{self.name} has no shape {name!r}; have {[s.name for s in self.shapes]}")
 
 
-# Family adapters ------------------------------------------------------------
+def _swin_forward(cfg, params, state, images, *, train: bool):
+    return vision.swin_forward(cfg, params, images), state
 
-_HAS_STATE = {"resnet", "effnet"}
+
+# family -> (abstract (params, state) specs, forward(cfg, params, state,
+# images, *, train) -> (logits, new_state))
+_FAMILIES = {
+    "resnet": (convnets.resnet_abstract, convnets.resnet_forward),
+    "squeezenet": (convnets.squeezenet_abstract, convnets.squeezenet_forward),
+    "swin": (lambda cfg: (vision.swin_abstract_params(cfg), {}), _swin_forward),
+}
 
 
 def abstract_params(arch: Arch):
-    f = arch.family
-    if f == "lm":
-        return lm.abstract_params(arch.cfg), {}
-    if f == "dit":
-        return diffusion.dit_abstract_params(arch.cfg), {}
-    if f == "flux":
-        return diffusion.flux_abstract_params(arch.cfg), {}
-    if f == "vit":
-        return vision.vit_abstract_params(arch.cfg), {}
-    if f == "swin":
-        return vision.swin_abstract_params(arch.cfg), {}
-    if f == "resnet":
-        return convnets.resnet_abstract(arch.cfg)
-    if f == "effnet":
-        return convnets.effnet_abstract(arch.cfg)
-    if f == "squeezenet":
-        return convnets.squeezenet_abstract(arch.cfg)
-    raise ValueError(f"unknown family {f}")
+    return _FAMILIES[arch.family][0](arch.cfg)
 
 
 def classifier_forward(arch: Arch, params, state, images, *, train: bool):
-    f = arch.family
-    if f == "vit":
-        return vision.vit_forward(arch.cfg, params, images), state
-    if f == "swin":
-        return vision.swin_forward(arch.cfg, params, images), state
-    if f == "resnet":
-        return convnets.resnet_forward(arch.cfg, params, state, images, train=train)
-    if f == "effnet":
-        return convnets.effnet_forward(arch.cfg, params, state, images, train=train)
-    if f == "squeezenet":
-        return convnets.squeezenet_forward(arch.cfg, params, state, images, train=train)
-    raise ValueError(f"{f} is not a classifier family")
-
-
-def n_params(arch: Arch) -> int:
-    return param_count(abstract_params(arch)[0])
-
-
-# Input specs per (arch, shape) ----------------------------------------------
-
-
-def _img_latent(arch: Arch, img: int) -> tuple[int, int]:
-    if arch.family == "dit":
-        return img // 8, arch.cfg.in_ch
-    if arch.family == "flux":
-        return img // 8, arch.cfg.in_ch
-    raise ValueError
-
-
-def input_specs(arch: Arch, shape: ShapeSpec) -> dict[str, ParamSpec]:
-    """Abstract (ShapeDtypeStruct-convertible) batch inputs with logical axes.
-
-    Returned as ParamSpec so launch/ can derive both ShapeDtypeStructs and
-    shardings from one object.
-    """
-    B = shape.batch
-    f = arch.family
-    if f == "lm":
-        if shape.kind == "train":
-            return {
-                "tokens": spec((B, shape.seq), ("batch", "seq"), dtype=jnp.int32, init="zeros"),
-                "labels": spec((B, shape.seq), ("batch", "seq"), dtype=jnp.int32, init="zeros"),
-            }
-        if shape.kind == "prefill":
-            return {"tokens": spec((B, shape.seq), ("batch", "seq"), dtype=jnp.int32, init="zeros")}
-        if shape.kind == "decode":
-            return {"token": spec((B, 1), ("batch", None), dtype=jnp.int32, init="zeros")}
-    if f in ("dit", "flux"):
-        lat, ch = _img_latent(arch, shape.img)
-        base = {
-            "x": spec((B, lat, lat, ch), ("batch", None, None, None)),
-            "t": spec((B,), ("batch",)),
-        }
-        if f == "dit":
-            base["y"] = spec((B,), ("batch",), dtype=jnp.int32, init="zeros")
-        else:
-            base["txt"] = spec((B, arch.cfg.txt_len, arch.cfg.txt_dim), ("batch", None, None))
-            base["vec"] = spec((B, arch.cfg.vec_dim), ("batch", None))
-            base["guidance"] = spec((B,), ("batch",))
-        if shape.kind == "denoise_train":
-            base["noise"] = spec((B, lat, lat, ch), ("batch", None, None, None))
-        else:
-            base["dt"] = spec((B,), ("batch",))
-        return base
-    if f in ("vit", "swin", "resnet", "effnet", "squeezenet"):
-        base = {"images": spec((B, shape.img, shape.img, 3), ("batch", "spatial", None, None))}
-        if shape.kind == "classify_train":
-            base["labels"] = spec((B,), ("batch",), dtype=jnp.int32, init="zeros")
-        return base
-    raise ValueError(f"no input spec for {arch.name}/{shape.name}")
-
-
-def make_inputs(arch: Arch, shape: ShapeSpec, key=None) -> dict[str, jax.Array]:
-    """Concrete (small-scale test) inputs for smoke tests."""
-    key = key if key is not None else jax.random.key(0)
-    specs = input_specs(arch, shape)
-    out = {}
-    for i, (name, s) in enumerate(sorted(specs.items())):
-        k = jax.random.fold_in(key, i)
-        if jnp.issubdtype(s.dtype, jnp.integer):
-            hi = 1000
-            if arch.family == "lm":
-                hi = arch.cfg.vocab
-            elif name == "y":
-                hi = arch.cfg.n_classes
-            elif name == "labels":
-                hi = getattr(arch.cfg, "n_classes", 1000)
-            out[name] = jax.random.randint(k, s.shape, 0, hi, dtype=s.dtype)
-        else:
-            if name == "t":
-                out[name] = jax.random.uniform(k, s.shape, s.dtype, 0.02, 0.98)
-            elif name == "dt":
-                out[name] = jnp.full(s.shape, 0.02, s.dtype)
-            elif name == "guidance":
-                out[name] = jnp.full(s.shape, 4.0, s.dtype)
-            else:
-                out[name] = jax.random.normal(k, s.shape, s.dtype)
-    return out
+    return _FAMILIES[arch.family][1](arch.cfg, params, state, images, train=train)

@@ -1,6 +1,5 @@
-"""Architecture registry: --arch <id> resolves here.
+"""Architecture registry: ``get(name)`` resolves a served classifier.
 
-10 assigned architectures + the paper's own compact model (squeezenet).
 Each module exports CONFIG (the exact published config) and SMOKE (a reduced
 same-family config for CPU tests).
 """
@@ -11,20 +10,11 @@ import importlib
 from ..arch import Arch
 
 _MODULES = {
-    "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
-    "deepseek-moe-16b": "deepseek_moe_16b",
-    "qwen3-0.6b": "qwen3_0_6b",
-    "command-r-35b": "command_r_35b",
-    "dit-xl2": "dit_xl2",
-    "flux-dev": "flux_dev",
-    "vit-s16": "vit_s16",
-    "efficientnet-b7": "efficientnet_b7",
-    "swin-b": "swin_b",
     "resnet-50": "resnet_50",
     "squeezenet": "squeezenet",
+    "swin-b": "swin_b",
 }
 
-ASSIGNED = tuple(k for k in _MODULES if k != "squeezenet")
 ALL = tuple(_MODULES)
 
 
@@ -33,13 +23,3 @@ def get(name: str, *, smoke: bool = False) -> Arch:
         raise KeyError(f"unknown arch {name!r}; available: {sorted(_MODULES)}")
     mod = importlib.import_module(f".{_MODULES[name]}", __package__)
     return mod.SMOKE if smoke else mod.CONFIG
-
-
-def cells() -> list[tuple[str, str]]:
-    """All 40 assigned (arch, shape) dry-run cells (+ squeezenet's 4 extra)."""
-    out = []
-    for name in ASSIGNED:
-        a = get(name)
-        for s in a.shapes:
-            out.append((name, s.name))
-    return out

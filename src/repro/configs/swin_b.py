@@ -10,20 +10,17 @@ Departures from the source, in ``models/vision.py``:
   (patch embedding, ``qkv``, ``proj``, ``fc1``, ``fc2``, each patch-merge
   reduction, the head) is an int8 GEMM on ``kernels/npu_matmul``; the two
   attention products of each window (scores and values) stay bf16 on every
-  path, as depthwise convolutions do in ``convnets``.
+  path.
 * A shifted window's region mask sets a score to -1e30 where the source
   adds -100: exp(-100) < 4e-44, so no softmax row moves by f32 resolution.
 """
 from ..arch import Arch
 from ..models import vision
-from .shapes import VISION_SHAPES
 
 CONFIG = Arch(
     name="swin-b",
     family="swin",
     cfg=vision.SwinConfig(name="swin-b", img_res=224),
-    shapes=VISION_SHAPES,
-    notes="cls_384 uses window 12 (as Swin-B-384 does) via per-shape cfg override.",
 )
 
 SMOKE = Arch(
@@ -39,5 +36,4 @@ SMOKE = Arch(
         n_heads=(2, 4),
         n_classes=10,
     ),
-    shapes=VISION_SHAPES,
 )

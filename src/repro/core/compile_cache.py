@@ -9,8 +9,7 @@ One rule places it: ``JAX_COMPILATION_CACHE_DIR`` when that is set (and
 then no code sets another directory), otherwise the fixed ``.jax_cache/``
 at the checkout root, resolved from this file and not from the working
 directory.  Entry points (``chip_smoke.py``, ``launch/serve.py``, the
-sweep CLI, ``sweep_bench``'s scale cell) enable it before their first
-compile; library calls such as ``Session.run_sweep`` leave it alone.
+sweep CLI) enable it before their first compile; library calls such as ``Session.run_sweep`` leave it alone.
 Tests place it by setting the environment variable.  Combined with the
 bucketing policy (:mod:`.bucketing` — stable shapes => byte-identical
 jaxprs => identical cache keys), a re-run of any sweep on a warm

@@ -3,7 +3,8 @@
 The Python implementations in max_accuracy/max_utility are the reference
 semantics; these run the same recurrences as fixed-shape tensor programs so a
 serving loop can schedule *on device* in microseconds (the paper reports
-< 1 ms on a phone CPU; benchmarks/sched_latency.py measures ours).
+< 1 ms on a phone CPU; ``benchmarks/paper_figures.py::sched_latency``
+measures ours).
 
   local_accuracy_dp_jax   H(k, t) over a time grid     (scan over frames)
   local_utility_dp_jax    fixed-width Pareto front DP  (scan over frames)

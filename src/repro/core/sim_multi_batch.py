@@ -125,8 +125,8 @@ __all__ = [
 ]
 
 # The equivalence contract versus the reference event loop, stated once for
-# every consumer (tests/test_sim_multi_batch.py asserts it per golden grid,
-# benchmarks/multistream_bench.py per ladder cell): the per-stream integer
+# every consumer (tests/test_sim_multi_batch.py asserts it per golden grid
+# and per ladder grid): the per-stream integer
 # fields below must match EXACTLY, float stats (accuracy sums, server busy
 # seconds) within the certified absolute tolerance MULTI_TOL.
 MULTI_TOL = 1e-9

@@ -18,19 +18,19 @@ same calling convention: ``n_lane`` leading arguments carry the scenario
   ``shard_map`` over the mesh's ``scenario`` axis with shared tables
   replicated, and outputs are sliced back to the true lane count.
 
-The mesh comes from :func:`repro.launch.mesh.make_sweep_mesh` and the
-partition specs from :func:`repro.sharding.rules.sweep_rules` — the rules'
-divisibility guard is what certifies the padded lane count actually
-shards.
+The mesh is :func:`make_sweep_mesh`, one flat ``scenario`` axis over
+every local device; lane arguments and outputs are split on it, and the
+padding above makes every lane count divide it.
 
 Scenario lane buffers are deliberately **not** donated.  A planner never
 reads a lane argument after the call, so donation looks free — but on
 jax 0.4.37 on the CPU an executable compiled with ``donate_argnums`` and
 *reloaded from the persistent compilation cache* returns corrupted stats
 for a nondeterministic subset of lanes (reproduced and bisected to
-donation by the scale bench: clean with donation off, hundreds of zeroed
-lanes with it on).  The inputs are host-built numpy chunks anyway, so
-donation never had an allocation to reuse here — correctness wins.
+donation on a streamed 10k-point sweep: clean with donation off, hundreds
+of zeroed lanes with it on).  The inputs are host-built numpy chunks
+anyway, so donation never had an allocation to reuse here — correctness
+wins.
 """
 from __future__ import annotations
 
@@ -42,8 +42,20 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..launch.mesh import make_sweep_mesh
-from ..sharding.rules import MeshRules, sweep_rules
+
+@lru_cache(maxsize=None)
+def make_sweep_mesh() -> Mesh:
+    """1-D mesh over every local device, for scenario-parallel sweep groups.
+
+    The sweep engines shard only the scenario (lane) axis — planner programs
+    are embarrassingly parallel across lanes, so a flat mesh uses every
+    device with zero cross-device traffic.  Cached: the device topology is
+    fixed for the life of the process, and callers key compiled sharded
+    programs on this mesh object.
+    """
+    return jax.make_mesh((jax.device_count(),), ("scenario",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+
 
 def _shard_enabled() -> bool:
     return os.environ.get("REPRO_SWEEP_SHARD", "1") != "0"
@@ -82,11 +94,7 @@ class LaneProgram:
 @lru_cache(maxsize=None)
 def _sharded_jit(prog: LaneProgram, mesh: Mesh):
     """jit(shard_map(program)) over the sweep mesh, one per (program, mesh)."""
-    rules = MeshRules(mesh, sweep_rules(mesh))
-    # Resolved at the mesh extent itself: padding guarantees divisibility,
-    # and the rules' guard would replicate (never mis-shard) anything else.
-    lane = rules._resolve((mesh.size,), ("scenario",))
-    assert lane != P(), "sweep mesh must expose a scenario/batch axis"
+    lane = P("scenario")
     in_specs = tuple(lane if i < prog.n_lane else P() for i in range(prog.n_args))
     sm = jax.shard_map(
         prog._vmapped, mesh=mesh, in_specs=in_specs,

@@ -2,8 +2,8 @@
 
   npu_matmul       w8a8 int8 GEMM with fused dequant epilogue — the FastVA
                    "NPU path" (the paper's 8-bit phone NPU, TPU-native).
-  flash_attention  online-softmax attention, GQA-folded tiles — kills the
-                   O(S^2) HBM traffic the roofline flags on prefill cells.
+  flash_attention  online-softmax attention, GQA-folded tiles (no model
+                   calls it yet; its tests hold it to its references).
 
 Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper
 with backend dispatch), ref.py (pure-jnp oracle).  CPU validation runs the

@@ -5,7 +5,7 @@ TPU-native design decisions (vs the CUDA flash-attention):
     online-softmax accumulators (m, l, acc) live in VMEM scratch across the
     KV sweep — the TPU analogue of a CUDA thread-block's shared-memory state.
   * Q/K/V blocks are tiled (block_q x hd) / (block_kv x hd) in VMEM; hd is a
-    full lane dimension (128 for every assigned arch), so MXU matmuls are
+    full lane dimension (128 in the tests' widest cases), so MXU matmuls are
     (block_q x hd) @ (hd x block_kv) — both operands hardware-aligned.
   * GQA: the G query heads of one KV head are FOLDED into the q-block rows
     ((G*Sq) x hd), so grouped queries share the K/V block loads through VMEM
@@ -14,9 +14,8 @@ TPU-native design decisions (vs the CUDA flash-attention):
   * causal masking via block-index arithmetic; fully-masked blocks still run
     (Pallas TPU grids are dense) but their contribution is exactly zero.
 
-Forward only: the backward pass uses XLA's autodiff through the jnp oracle
-(models fall back to blockwise_sdpa for training).  Serving (prefill/decode)
-is where the paper's latency story lives, and that is forward-only.
+Forward only: a backward pass would differentiate the jnp oracle
+(``ref.blockwise_ref``).  No served model calls the kernel yet.
 """
 from __future__ import annotations
 

@@ -1,15 +1,10 @@
-"""Model substrate foundations: abstract parameter specs, logical sharding
-axes, and materialization — the contract every model family implements.
+"""Model foundations: abstract parameter specs, their materialization, and
+the matmul seam — the contract each of the three served classifier families
+(``convnets``: ResNet-50, SqueezeNet; ``vision``: Swin-B) implements.
 
 A model family provides:
   abstract_params(cfg) -> dict[str, ParamSpec]      (nested dicts allowed)
-  apply(cfg, params, *inputs) -> outputs            (pure function)
-
-ParamSpec carries the *logical* axis names of each dimension; the sharding
-rules (sharding/rules.py) map logical names -> mesh axes, skipping any axis
-whose size does not divide the mesh extent and never assigning the same mesh
-axis twice within one spec.  That one guard is what lets a single rule table
-cover 11 architectures x 3 shapes x 2 meshes.
+  forward(cfg, params, ..., images) -> logits       (pure function)
 """
 from __future__ import annotations
 
@@ -19,7 +14,6 @@ from typing import Any, Iterable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 Pytree = Any
 
@@ -27,17 +21,23 @@ Pytree = Any
 @dataclasses.dataclass(frozen=True)
 class ParamSpec:
     shape: tuple[int, ...]
-    axes: tuple[str | None, ...]  # logical axis name per dim
     dtype: Any = jnp.float32
-    init: str = "normal"  # normal | zeros | ones | embed | conv | scaled
+    init: str = "normal"  # normal | zeros | ones | conv
     scale: float | None = None  # override fan-in scaling
 
-    def __post_init__(self) -> None:
-        assert len(self.shape) == len(self.axes), (self.shape, self.axes)
+
+def spec(shape: Iterable[int], **kw) -> ParamSpec:
+    return ParamSpec(tuple(int(s) for s in shape), **kw)
 
 
-def spec(shape: Iterable[int], axes: Iterable[str | None], **kw) -> ParamSpec:
-    return ParamSpec(tuple(int(s) for s in shape), tuple(axes), **kw)
+def stack(specs: Pytree, n: int) -> Pytree:
+    """Add a leading dim of ``n`` to every spec in the tree: the parameters
+    of ``n`` identical blocks that a ``lax.scan`` runs in turn."""
+    return jax.tree.map(
+        lambda s: dataclasses.replace(s, shape=(n, *s.shape)),
+        specs,
+        is_leaf=lambda x: isinstance(x, ParamSpec),
+    )
 
 
 def _fan_in(shape: tuple[int, ...], init: str) -> float:
@@ -46,8 +46,6 @@ def _fan_in(shape: tuple[int, ...], init: str) -> float:
     if init == "conv":  # HWIO
         rf = math.prod(shape[:-2]) if len(shape) > 2 else 1
         return float(rf * shape[-2])
-    if init == "embed":
-        return 1.0
     return float(shape[-2]) if len(shape) >= 2 else float(shape[0])
 
 
@@ -73,70 +71,6 @@ def abstract_tree(specs: Pytree) -> Pytree:
         specs,
         is_leaf=lambda x: isinstance(x, ParamSpec),
     )
-
-
-def param_count(specs: Pytree) -> int:
-    leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
-    return sum(math.prod(s.shape) for s in leaves)
-
-
-def param_bytes(specs: Pytree) -> int:
-    leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
-    return sum(math.prod(s.shape) * np.dtype(s.dtype).itemsize for s in leaves)
-
-
-# ---------------------------------------------------------------------------
-# dtype policy (mixed precision)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class Policy:
-    param_dtype: Any = jnp.float32
-    compute_dtype: Any = jnp.bfloat16
-    output_dtype: Any = jnp.float32
-
-    def cast(self, tree: Pytree) -> Pytree:
-        c = self.compute_dtype
-        return jax.tree.map(lambda x: x.astype(c) if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
-
-
-TRAIN_POLICY = Policy()
-SERVE_POLICY = Policy(param_dtype=jnp.bfloat16, compute_dtype=jnp.bfloat16, output_dtype=jnp.float32)
-
-
-# ---------------------------------------------------------------------------
-# Activation sharding helper — models call shard(x, "batch", "seq", "embed")
-# and the active MeshRules (set by launch/train/serve) resolves it.  Outside
-# a mesh context it is the identity, so smoke tests never see 512 devices.
-# ---------------------------------------------------------------------------
-
-_ACTIVE_RULES: list[Any] = []
-
-
-class activation_rules:
-    """Context manager installing a MeshRules for shard() calls."""
-
-    def __init__(self, rules: Any):
-        self.rules = rules
-
-    def __enter__(self):
-        _ACTIVE_RULES.append(self.rules)
-        return self.rules
-
-    def __exit__(self, *exc):
-        _ACTIVE_RULES.pop()
-
-
-def shard(x: jax.Array, *axes: str | None) -> jax.Array:
-    if not _ACTIVE_RULES:
-        return x
-    rules = _ACTIVE_RULES[-1]
-    return rules.constrain(x, axes)
-
-
-def current_rules():
-    return _ACTIVE_RULES[-1] if _ACTIVE_RULES else None
 
 
 # ---------------------------------------------------------------------------

@@ -1,32 +1,28 @@
-"""Convolutional classifiers: ResNet-50, EfficientNet-B7, SqueezeNet.
+"""Convolutional classifiers: ResNet-50 and SqueezeNet.
 
 BatchNorm models carry a separate mutable ``state`` tree (running mean/var);
-``forward(..., train=True)`` returns (logits, new_state).  Stages scan their
-repeated identical blocks (stacked params) so the 512-device SPMD compile of
-EfficientNet-B7's 55 blocks stays tractable.
+``forward(..., train=True)`` returns (logits, new_state).  ResNet's stages
+scan their repeated identical blocks (stacked params).
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-import math
-
 
 import jax
 import jax.numpy as jnp
 
-from .common import current_matmul, matmul, shard, spec
-from .lm import _stack
+from .common import current_matmul, matmul, spec, stack
 
 BN_MOMENTUM = 0.9
 
 
-def conv_spec(kh, kw, cin, cout, name_in="conv_in", name_out="conv_out"):
-    return spec((kh, kw, cin, cout), (None, None, name_in, name_out), init="conv")
+def conv_spec(kh, kw, cin, cout):
+    return spec((kh, kw, cin, cout), init="conv")
 
 
-def conv(p, x, stride=1, padding="SAME", groups=1):
-    if current_matmul() is not None and groups == 1:
+def conv(p, x, stride=1, padding="SAME"):
+    if current_matmul() is not None:
         return _conv_via_matmul(p, x, stride, padding)
     return jax.lax.conv_general_dilated(
         x,
@@ -34,7 +30,6 @@ def conv(p, x, stride=1, padding="SAME", groups=1):
         window_strides=(stride, stride),
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=groups,
     )
 
 
@@ -42,9 +37,7 @@ def _conv_via_matmul(p, x, stride, padding):
     """im2col lowering: the conv as ONE [B*H'*W', KH*KW*Cin] x [., Cout] GEMM
     through the active matmul backend — how NPUs (and the int8 Pallas path)
     actually execute convolutions.  The patches are in (kh, kw, cin) order,
-    so the weight is its HWIO array reshaped, with no transpose.  Depthwise
-    convs (groups > 1) stay on lax.conv: they are channel-parallel scalar
-    products, not GEMMs."""
+    so the weight is its HWIO array reshaped, with no transpose."""
     kh, kw, cin, cout = p.shape
     w2d = p.reshape(kh * kw * cin, cout).astype(x.dtype)
     return matmul(im2col(x, kh, kw, stride, padding), w2d)
@@ -131,21 +124,21 @@ def im2col(x, kh, kw, stride, padding):
 
 def bn_specs(ch):
     return {
-        "scale": spec((ch,), ("channels",), init="ones"),
-        "bias": spec((ch,), ("channels",), init="zeros"),
+        "scale": spec((ch,), init="ones"),
+        "bias": spec((ch,), init="zeros"),
     }
 
 
 def bn_state_specs(ch):
     return {
-        "mean": spec((ch,), ("channels",), init="zeros"),
-        "var": spec((ch,), ("channels",), init="ones"),
+        "mean": spec((ch,), init="zeros"),
+        "var": spec((ch,), init="ones"),
     }
 
 
 def batchnorm(p, s, x, train: bool, eps=1e-5):
-    """Returns (y, new_state).  In SPMD training the jnp.mean over the global
-    batch/space dims is what produces the cross-device all-reduce (sync-BN)."""
+    """Returns (y, new_state): batch statistics when training, the running
+    ones otherwise."""
     if train:
         x32 = x.astype(jnp.float32)
         mean = jnp.mean(x32, axis=(0, 1, 2))
@@ -215,12 +208,12 @@ def resnet_abstract(c: ResNetConfig) -> tuple[dict, dict]:
         params[f"stage{i}_first"] = _bottleneck_specs(cin, cmid, cout, stride)
         state[f"stage{i}_first"] = _bottleneck_state(cin, cmid, cout, stride)
         if depth > 1:
-            params[f"stage{i}_rest"] = _stack(_bottleneck_specs(cout, cmid, cout, 1), depth - 1)
-            state[f"stage{i}_rest"] = _stack(_bottleneck_state(cout, cmid, cout, 1), depth - 1)
+            params[f"stage{i}_rest"] = stack(_bottleneck_specs(cout, cmid, cout, 1), depth - 1)
+            state[f"stage{i}_rest"] = stack(_bottleneck_state(cout, cmid, cout, 1), depth - 1)
         cin = cout
     params["head"] = {
-        "w": spec((cin, c.n_classes), ("embed", "vocab")),
-        "b": spec((c.n_classes,), ("vocab",), init="zeros"),
+        "w": spec((cin, c.n_classes)),
+        "b": spec((c.n_classes,), init="zeros"),
     }
     return params, state
 
@@ -260,155 +253,7 @@ def resnet_forward(c: ResNetConfig, params, state, images, *, train: bool = Fals
             x, ns[f"stage{i}_rest"] = jax.lax.scan(
                 body, x, (params[f"stage{i}_rest"], state[f"stage{i}_rest"])
             )
-        x = shard(x, "batch", None, None, None)
     h = x.mean(axis=(1, 2))
-    logits = matmul(h, params["head"]["w"].astype(h.dtype)) + params["head"]["b"].astype(h.dtype)
-    return logits.astype(jnp.float32), ns
-
-
-# ---------------------------------------------------------------------------
-# EfficientNet (B0 base scaled by width/depth multipliers; B7 = 2.0 / 3.1)
-# ---------------------------------------------------------------------------
-
-EFFNET_B0_BLOCKS = (  # (expand, channels, repeats, stride, kernel)
-    (1, 16, 1, 1, 3),
-    (6, 24, 2, 2, 3),
-    (6, 40, 2, 2, 5),
-    (6, 80, 3, 2, 3),
-    (6, 112, 3, 1, 5),
-    (6, 192, 4, 2, 5),
-    (6, 320, 1, 1, 3),
-)
-
-
-def _round_filters(ch: float, mult: float, divisor: int = 8) -> int:
-    ch *= mult
-    new = max(divisor, int(ch + divisor / 2) // divisor * divisor)
-    if new < 0.9 * ch:
-        new += divisor
-    return int(new)
-
-
-@dataclasses.dataclass(frozen=True)
-class EfficientNetConfig:
-    name: str
-    width_mult: float = 1.0
-    depth_mult: float = 1.0
-    n_classes: int = 1000
-    se_ratio: float = 0.25
-
-    def stages(self):
-        out = []
-        for expand, ch, reps, stride, k in EFFNET_B0_BLOCKS:
-            out.append(
-                (
-                    expand,
-                    _round_filters(ch, self.width_mult),
-                    int(math.ceil(reps * self.depth_mult)),
-                    stride,
-                    k,
-                )
-            )
-        return out
-
-    @property
-    def stem_ch(self) -> int:
-        return _round_filters(32, self.width_mult)
-
-    @property
-    def head_ch(self) -> int:
-        return _round_filters(1280, self.width_mult)
-
-
-def _mbconv_specs(cin, cout, expand, k, se_ratio):
-    cmid = cin * expand
-    s: dict = {}
-    if expand != 1:
-        s["expand"] = conv_spec(1, 1, cin, cmid)
-        s["bn_e"] = bn_specs(cmid)
-    s["dw"] = spec((k, k, 1, cmid), (None, None, None, "conv_out"), init="conv")
-    s["bn_d"] = bn_specs(cmid)
-    cse = max(1, int(cin * se_ratio))
-    s["se_r"] = {"w": conv_spec(1, 1, cmid, cse), "b": spec((cse,), (None,), init="zeros")}
-    s["se_e"] = {"w": conv_spec(1, 1, cse, cmid), "b": spec((cmid,), (None,), init="zeros")}
-    s["project"] = conv_spec(1, 1, cmid, cout)
-    s["bn_p"] = bn_specs(cout)
-    return s
-
-
-def _mbconv_state(cin, cout, expand):
-    cmid = cin * expand
-    s: dict = {"bn_d": bn_state_specs(cmid), "bn_p": bn_state_specs(cout)}
-    if expand != 1:
-        s["bn_e"] = bn_state_specs(cmid)
-    return s
-
-
-def effnet_abstract(c: EfficientNetConfig) -> tuple[dict, dict]:
-    params: dict = {"stem": {"conv": conv_spec(3, 3, 3, c.stem_ch), "bn": bn_specs(c.stem_ch)}}
-    state: dict = {"stem": {"bn": bn_state_specs(c.stem_ch)}}
-    cin = c.stem_ch
-    for i, (expand, cout, reps, stride, k) in enumerate(c.stages()):
-        params[f"stage{i}_first"] = _mbconv_specs(cin, cout, expand, k, c.se_ratio)
-        state[f"stage{i}_first"] = _mbconv_state(cin, cout, expand)
-        if reps > 1:
-            params[f"stage{i}_rest"] = _stack(_mbconv_specs(cout, cout, expand, k, c.se_ratio), reps - 1)
-            state[f"stage{i}_rest"] = _stack(_mbconv_state(cout, cout, expand), reps - 1)
-        cin = cout
-    params["head_conv"] = {"conv": conv_spec(1, 1, cin, c.head_ch), "bn": bn_specs(c.head_ch)}
-    state["head_conv"] = {"bn": bn_state_specs(c.head_ch)}
-    params["head"] = {
-        "w": spec((c.head_ch, c.n_classes), ("embed", "vocab")),
-        "b": spec((c.n_classes,), ("vocab",), init="zeros"),
-    }
-    return params, state
-
-
-def _mbconv(p, s, x, stride, k, train):
-    ns: dict = {}
-    h = x
-    if "expand" in p:
-        h, ns["bn_e"] = batchnorm(p["bn_e"], s["bn_e"], conv(p["expand"], h), train)
-        h = jax.nn.silu(h)
-    cmid = h.shape[-1]
-    h2 = conv(p["dw"], h, stride=stride, groups=cmid)
-    h, ns["bn_d"] = batchnorm(p["bn_d"], s["bn_d"], h2, train)
-    h = jax.nn.silu(h)
-    # Squeeze-and-excitation.
-    z = h.mean(axis=(1, 2), keepdims=True)
-    z = jax.nn.silu(conv(p["se_r"]["w"], z) + p["se_r"]["b"].astype(z.dtype))
-    z = jax.nn.sigmoid(conv(p["se_e"]["w"], z) + p["se_e"]["b"].astype(z.dtype))
-    h = h * z
-    h, ns["bn_p"] = batchnorm(p["bn_p"], s["bn_p"], conv(p["project"], h), train)
-    if stride == 1 and x.shape[-1] == h.shape[-1]:
-        h = h + x
-    return h, ns
-
-
-def effnet_forward(c: EfficientNetConfig, params, state, images, *, train: bool = False):
-    x = images.astype(jnp.bfloat16)
-    ns: dict = {"stem": {}, "head_conv": {}}
-    x = conv(params["stem"]["conv"], x, stride=2)
-    x, ns["stem"]["bn"] = batchnorm(params["stem"]["bn"], state["stem"]["bn"], x, train)
-    x = jax.nn.silu(x)
-    for i, (expand, cout, reps, stride, k) in enumerate(c.stages()):
-        x, ns[f"stage{i}_first"] = _mbconv(
-            params[f"stage{i}_first"], state[f"stage{i}_first"], x, stride, k, train
-        )
-        if reps > 1:
-
-            def body(x, ps, k=k):
-                p, s = ps
-                y, s2 = _mbconv(p, s, x, 1, k, train)
-                return y, s2
-
-            x, ns[f"stage{i}_rest"] = jax.lax.scan(
-                body, x, (params[f"stage{i}_rest"], state[f"stage{i}_rest"])
-            )
-        x = shard(x, "batch", None, None, None)
-    x = conv(params["head_conv"]["conv"], x)
-    x, ns["head_conv"]["bn"] = batchnorm(params["head_conv"]["bn"], state["head_conv"]["bn"], x, train)
-    h = jax.nn.silu(x).mean(axis=(1, 2))
     logits = matmul(h, params["head"]["w"].astype(h.dtype)) + params["head"]["b"].astype(h.dtype)
     return logits.astype(jnp.float32), ns
 
@@ -433,15 +278,15 @@ FIRE_CFG = (  # (squeeze, expand) after each pool stage
 
 def _fire_specs(cin, sq, ex):
     return {
-        "squeeze": {"w": conv_spec(1, 1, cin, sq), "b": spec((sq,), (None,), init="zeros")},
-        "e1": {"w": conv_spec(1, 1, sq, ex), "b": spec((ex,), (None,), init="zeros")},
-        "e3": {"w": conv_spec(3, 3, sq, ex), "b": spec((ex,), (None,), init="zeros")},
+        "squeeze": {"w": conv_spec(1, 1, cin, sq), "b": spec((sq,), init="zeros")},
+        "e1": {"w": conv_spec(1, 1, sq, ex), "b": spec((ex,), init="zeros")},
+        "e3": {"w": conv_spec(3, 3, sq, ex), "b": spec((ex,), init="zeros")},
     }
 
 
 def squeezenet_abstract(c: SqueezeNetConfig) -> tuple[dict, dict]:
     params: dict = {
-        "stem": {"w": conv_spec(3, 3, 3, 64), "b": spec((64,), (None,), init="zeros")}
+        "stem": {"w": conv_spec(3, 3, 3, 64), "b": spec((64,), init="zeros")}
     }
     cin = 64
     for gi, group in enumerate(FIRE_CFG):
@@ -450,7 +295,7 @@ def squeezenet_abstract(c: SqueezeNetConfig) -> tuple[dict, dict]:
             cin = 2 * ex
     params["classifier"] = {
         "w": conv_spec(1, 1, cin, c.n_classes),
-        "b": spec((c.n_classes,), (None,), init="zeros"),
+        "b": spec((c.n_classes,), init="zeros"),
     }
     return params, {}
 

@@ -1,121 +1,17 @@
-"""Vision transformers: ViT (plain) and Swin (windowed, shifted).
-
-Both are encoder-only classifiers: forward(cfg, params, images) -> logits.
-Patch embedding IS part of the model (per the assignment: vision archs embed
-their own stem, unlike the LM pool's VLM stubs).
+"""Swin Transformer (windowed, shifted): an encoder-only classifier,
+``swin_forward(cfg, params, images) -> logits``, its patch embedding part
+of the model.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import layers as L
-from .common import matmul, shard, spec
+from .common import matmul, spec
 from .convnets import conv
-from .lm import _stack
-
-# ---------------------------------------------------------------------------
-# ViT
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class ViTConfig:
-    name: str
-    img_res: int
-    patch: int
-    n_layers: int
-    d_model: int
-    n_heads: int
-    d_ff: int
-    n_classes: int = 1000
-    remat: bool = False
-
-    @property
-    def n_patches(self) -> int:
-        return (self.img_res // self.patch) ** 2
-
-    def attn_cfg(self) -> L.AttnCfg:
-        return L.AttnCfg(
-            d_model=self.d_model,
-            n_heads=self.n_heads,
-            n_kv_heads=self.n_heads,
-            head_dim=self.d_model // self.n_heads,
-            causal=False,
-            rope=False,
-            bias=True,
-        )
-
-
-def _vit_block_specs(c: ViTConfig) -> dict:
-    return {
-        "ln1": L.layernorm_specs(c.d_model),
-        "attn": L.attention_specs(c.attn_cfg()),
-        "ln2": L.layernorm_specs(c.d_model),
-        "mlp": L.mlp_specs(c.d_model, c.d_ff),
-    }
-
-
-def vit_abstract_params(c: ViTConfig) -> dict:
-    return {
-        "patch_embed": {
-            "w": spec((c.patch, c.patch, 3, c.d_model), (None, None, "conv_in", "embed"), init="conv"),
-            "b": spec((c.d_model,), ("embed",), init="zeros"),
-        },
-        "cls": spec((1, 1, c.d_model), (None, None, "embed"), scale=0.02),
-        "pos": spec((1, c.n_patches + 1, c.d_model), (None, None, "embed"), scale=0.02),
-        "blocks": _stack(_vit_block_specs(c), c.n_layers),
-        "ln_f": L.layernorm_specs(c.d_model),
-        "head": {
-            "w": spec((c.d_model, c.n_classes), ("embed", "vocab")),
-            "b": spec((c.n_classes,), ("vocab",), init="zeros"),
-        },
-    }
-
-
-def _vit_block(c: ViTConfig, p, x):
-    a, _ = L.attention(c.attn_cfg(), p["attn"], L.layernorm(p["ln1"], x))
-    x = shard(x + a, "batch", None, None)
-    f = L.mlp(p["mlp"], L.layernorm(p["ln2"], x))
-    return shard(x + f, "batch", None, None)
-
-
-def vit_forward(c: ViTConfig, params, images):
-    """images: [B, H, W, 3] -> logits [B, n_classes]."""
-    B = images.shape[0]
-    w = params["patch_embed"]["w"].astype(jnp.bfloat16)
-    x = jax.lax.conv_general_dilated(
-        images.astype(jnp.bfloat16),
-        w,
-        window_strides=(c.patch, c.patch),
-        padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    x = x.reshape(B, -1, c.d_model) + params["patch_embed"]["b"].astype(jnp.bfloat16)
-    cls = jnp.broadcast_to(params["cls"].astype(x.dtype), (B, 1, c.d_model))
-    x = jnp.concatenate([cls, x], axis=1) + params["pos"].astype(x.dtype)
-    x = shard(x, "batch", None, None)
-
-    def body(x, blk):
-        fn = partial(_vit_block, c)
-        if c.remat:
-            fn = jax.checkpoint(fn)
-        return fn(blk, x), None
-
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = L.layernorm(params["ln_f"], x)
-    h = x[:, 0]
-    logits = h @ params["head"]["w"].astype(h.dtype) + params["head"]["b"].astype(h.dtype)
-    return logits.astype(jnp.float32)
-
-
-# ---------------------------------------------------------------------------
-# Swin
-# ---------------------------------------------------------------------------
 
 SWIN_LN_EPS = 1e-5  # torch.nn.LayerNorm's default, as the source builds every norm
 
@@ -133,10 +29,23 @@ class SwinConfig:
     n_classes: int = 1000
 
 
-def _linear_specs(din: int, dout: int, axes: tuple[str, str], bias: bool = True) -> dict:
-    p = {"w": spec((din, dout), axes)}
+def layernorm_specs(dim: int) -> dict:
+    return {"scale": spec((dim,), init="ones"), "bias": spec((dim,), init="zeros")}
+
+
+def layernorm(params, x, eps: float):
+    dt = x.dtype
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.var(x32, axis=-1, keepdims=True)
+    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
+    return (y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)).astype(dt)
+
+
+def _linear_specs(din: int, dout: int, bias: bool = True) -> dict:
+    p = {"w": spec((din, dout))}
     if bias:
-        p["b"] = spec((dout,), (axes[1],), init="zeros")
+        p["b"] = spec((dout,), init="zeros")
     return p
 
 
@@ -144,16 +53,16 @@ def _swin_block_specs(c: SwinConfig, dim: int, heads: int) -> dict:
     w = c.window
     hidden = dim * c.mlp_ratio
     return {
-        "norm1": L.layernorm_specs(dim),
+        "norm1": layernorm_specs(dim),
         "attn": {
-            "qkv": _linear_specs(dim, 3 * dim, ("embed", "heads")),
-            "rel_bias": spec(((2 * w - 1) * (2 * w - 1), heads), (None, "heads"), scale=0.02),
-            "proj": _linear_specs(dim, dim, ("heads", "embed")),
+            "qkv": _linear_specs(dim, 3 * dim),
+            "rel_bias": spec(((2 * w - 1) * (2 * w - 1), heads), scale=0.02),
+            "proj": _linear_specs(dim, dim),
         },
-        "norm2": L.layernorm_specs(dim),
+        "norm2": layernorm_specs(dim),
         "mlp": {
-            "fc1": _linear_specs(dim, hidden, ("embed", "mlp")),
-            "fc2": _linear_specs(hidden, dim, ("mlp", "embed")),
+            "fc1": _linear_specs(dim, hidden),
+            "fc2": _linear_specs(hidden, dim),
         },
     }
 
@@ -165,22 +74,22 @@ def swin_abstract_params(c: SwinConfig) -> dict:
     p: dict = {
         "patch_embed": {
             "proj": {
-                "w": spec((c.patch, c.patch, 3, c.dims[0]), (None, None, "conv_in", "embed"), init="conv"),
-                "b": spec((c.dims[0],), ("embed",), init="zeros"),
+                "w": spec((c.patch, c.patch, 3, c.dims[0]), init="conv"),
+                "b": spec((c.dims[0],), init="zeros"),
             },
-            "norm": L.layernorm_specs(c.dims[0]),
+            "norm": layernorm_specs(c.dims[0]),
         }
     }
     for i, (depth, dim, heads) in enumerate(zip(c.depths, c.dims, c.n_heads)):
         stage: dict = {"blocks": [_swin_block_specs(c, dim, heads) for _ in range(depth)]}
         if i < len(c.depths) - 1:
             stage["merge"] = {
-                "norm": L.layernorm_specs(4 * dim),
-                "reduction": _linear_specs(4 * dim, c.dims[i + 1], ("embed", "mlp"), bias=False),
+                "norm": layernorm_specs(4 * dim),
+                "reduction": _linear_specs(4 * dim, c.dims[i + 1], bias=False),
             }
         p[f"stage{i}"] = stage
-    p["norm"] = L.layernorm_specs(c.dims[-1])
-    p["head"] = _linear_specs(c.dims[-1], c.n_classes, ("embed", "vocab"))
+    p["norm"] = layernorm_specs(c.dims[-1])
+    p["head"] = _linear_specs(c.dims[-1], c.n_classes)
     return p
 
 
@@ -192,7 +101,7 @@ def _linear(p, x):
 
 
 def _norm(p, x):
-    return L.layernorm(p, x, eps=SWIN_LN_EPS)
+    return layernorm(p, x, eps=SWIN_LN_EPS)
 
 
 def _rel_bias_index(w: int) -> np.ndarray:
@@ -297,7 +206,7 @@ def swin_forward(c: SwinConfig, params, images):
             raise ValueError(f"{c.name}: stage {i}'s {H}x{W} token grid is not whole {w}x{w} windows")
         for j, blk in enumerate(stage["blocks"]):
             shift = w // 2 if j % 2 == 1 and min(H, W) > w else 0
-            x = shard(_swin_block(blk, x, H, W, w, heads, shift), "batch", None, None)
+            x = _swin_block(blk, x, H, W, w, heads, shift)
         if "merge" in stage:
             x = _patch_merge(stage["merge"], x, H, W)
             H, W = H // 2, W // 2

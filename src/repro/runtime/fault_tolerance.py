@@ -1,6 +1,7 @@
 """Fault tolerance & elasticity for multi-pod runs.
 
-Pieces (each independently unit-tested; the train driver wires them up):
+Pieces, each unit-tested on its own; no driver in this repo wires them
+together yet:
 
   HeartbeatMonitor    workers report liveness; missed-deadline detection with
                       a configurable grace window.  On a real cluster the
@@ -17,9 +18,7 @@ Pieces (each independently unit-tested; the train driver wires them up):
   plan_elastic_remesh Given surviving chips, pick the largest (pod, data,
                       model) mesh <= survivors that preserves the model axis
                       (TP degree is fixed by weight shardings), shrinking the
-                      data axis — then the restart path is: restore the last
-                      checkpoint with restore_resharded + skip-ahead the data
-                      pipeline (both deterministic).
+                      data axis.
 
 The FastVA tie-in: the serving tier treats an edge-pool failure exactly like
 the paper treats a network outage — the controller's profile for the edge

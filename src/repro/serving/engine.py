@@ -150,7 +150,7 @@ class BatchedEndpoint:
             # One flush per FORWARD, not per __call__: an oversized batch
             # split into max_batch chunks is several forwards, and counting
             # it as one would overstate mean_batch/pad_fraction — exactly
-            # the batching-efficiency stats the serving bench reports.
+            # the batching-efficiency stats the benchmark reports.
             self.stats.flushes += 1
         self.stats.frames += len(images)
         return np.concatenate(outs)

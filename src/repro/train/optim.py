@@ -1,9 +1,8 @@
 """AdamW + schedules, from scratch (no optax in this environment).
 
 Optimizer state is a pytree mirroring params: {"m": ..., "v": ...} in f32,
-plus a scalar step.  ``adamw_update`` is pure and jit-friendly; the sharding
-of m/v follows the param shardings (ZeRO-1/2 falls out of the FSDP param
-rules for free, since state mirrors the sharded master params).
+plus a scalar step.  ``adamw_update`` is pure and jit-friendly.
+``serving/calibrate.py`` trains its classifiers with it.
 """
 from __future__ import annotations
 

@@ -4,8 +4,9 @@ from pathlib import Path
 
 import pytest
 
-# Tests must see the real single CPU device (the dry-run sets 512 in its own
-# process); make sure no leaked XLA_FLAGS reach us.
+# Tests must see the real single CPU device (the multi-device sweep tests set
+# their own device count in a subprocess); make sure no leaked XLA_FLAGS
+# reach us.
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))

@@ -1,37 +1,15 @@
-"""Per-architecture smoke tests: every assigned arch (reduced config) runs a
-forward/train step on CPU with correct output shapes and finite values —
-plus LM decode==prefill consistency and the MoE dispatch invariants."""
+"""Per-architecture smoke tests: each served classifier (reduced config)
+runs an eval forward and a training gradient step on CPU with the right
+output shapes and finite values, plus the registry's error path."""
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro import configs
-from repro.arch import ShapeSpec
-from repro.launch import steps
-from repro.train.optim import AdamWConfig
-
-
-def _smoke_shapes(fam: str) -> list[ShapeSpec]:
-    if fam == "lm":
-        return [
-            ShapeSpec("t", "train", 2, seq=32),
-            ShapeSpec("p", "prefill", 2, seq=32),
-            ShapeSpec("d", "decode", 2, seq=32),
-        ]
-    if fam in ("dit", "flux"):
-        return [
-            ShapeSpec("t", "denoise_train", 2, img=64, steps=2),
-            ShapeSpec("g", "denoise_step", 2, img=64, steps=2),
-        ]
-    return [
-        ShapeSpec("t", "classify_train", 2, img=32),
-        ShapeSpec("s", "classify_serve", 2, img=32),
-    ]
+from repro.arch import abstract_params, classifier_forward
+from repro.models.common import init_tree
 
 
 def _all_finite(tree) -> bool:
@@ -42,109 +20,51 @@ def _all_finite(tree) -> bool:
     )
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch_name", configs.ALL)
 def test_arch_smoke(arch_name):
+    """An eval forward, then one ``jax.grad`` step of the training-mode
+    cross-entropy through ``classifier_forward``."""
     a = configs.get(arch_name, smoke=True)
-    shapes = _smoke_shapes(a.family)
-    a2 = dataclasses.replace(a, shapes=tuple(shapes))
-    for s in shapes:
-        prog = steps.build_cell(a2, s.name, adamw=AdamWConfig(warmup_steps=1, total_steps=4))
-        out = prog.jit()(*prog.init_args())
-        assert _all_finite(out), f"{arch_name}/{s.kind} produced non-finite values"
-        if s.kind == "train":
-            _, metrics = out
-            assert float(metrics["loss"]) > 0
-
-
-def test_lm_decode_matches_prefill():
-    from repro.models import lm
-    from repro.models.common import init_tree
-
-    a = configs.get("qwen3-0.6b", smoke=True)
-    cfg = a.cfg
-    params = init_tree(jax.random.key(0), lm.abstract_params(cfg))
-    tokens = jax.random.randint(jax.random.key(1), (2, 12), 0, cfg.vocab)
-    logits_p, _ = jax.jit(lambda p, t: lm.prefill(cfg, p, t, max_len=16))(params, tokens)
-    cache = lm.make_cache(cfg, 2, 16)
-    lg = None
-    step = jax.jit(lambda p, t, c: lm.decode_step(cfg, p, t, c))
-    for s in range(12):
-        lg, cache = step(params, tokens[:, s : s + 1], cache)
-    np.testing.assert_allclose(np.asarray(lg), np.asarray(logits_p), rtol=2e-4, atol=2e-4)
-
-
-def test_lm_train_loss_decreases():
-    a = configs.get("qwen3-0.6b", smoke=True)
-    a2 = dataclasses.replace(a, shapes=(ShapeSpec("t", "train", 4, seq=32),))
-    prog = steps.build_cell(a2, "t", adamw=AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=30))
-    step = prog.jit()
-    ts, batch = prog.init_args()
-    losses = []
-    for _ in range(15):
-        ts, metrics = step(ts, batch)  # overfit one batch
-        losses.append(float(metrics["loss"]))
-    assert losses[-1] < losses[0] * 0.8, losses
-
-
-def test_moe_dispatch_invariants():
-    from repro.models import layers as L
-
-    c = L.MoECfg(d_model=16, d_ff_expert=8, n_experts=4, top_k=2, capacity_factor=8.0)
-    N = 12 * 2  # tokens * k
-    eid = jax.random.randint(jax.random.key(0), (N,), 0, 4)
-    cap = int(round(N / 4 * 8.0))
-    token_idx, slot_valid, pos, kept = L._dispatch_indices(eid, 4, cap)
-    # with a huge capacity factor nothing drops
-    assert bool(jnp.all(kept))
-    # every valid slot maps to a token routed to that expert
-    for e in range(4):
-        for ci in range(cap):
-            if bool(slot_valid[e, ci]):
-                assert int(eid[token_idx[e, ci]]) == e
-    # and each kept token occupies exactly one valid slot
-    filled = int(slot_valid.sum())
-    assert filled == N
-
-
-def test_moe_capacity_drops_tokens():
-    from repro.models import layers as L
-
-    eid = jnp.zeros((16,), jnp.int32)  # everything routed to expert 0
-    token_idx, slot_valid, pos, kept = L._dispatch_indices(eid, 4, 4)
-    assert int(kept.sum()) == 4  # capacity 4 -> 4 kept, 12 dropped
-    assert int(slot_valid[0].sum()) == 4
-
-
-def test_moe_matches_dense_when_single_expert():
-    """1 expert + top-1 + ample capacity == plain SwiGLU with that expert."""
-    from repro.models import layers as L
-    from repro.models.common import init_tree
-
-    c = L.MoECfg(d_model=32, d_ff_expert=64, n_experts=1, top_k=1, capacity_factor=2.0)
-    p = init_tree(jax.random.key(0), L.moe_specs(c))
-    x = jax.random.normal(jax.random.key(1), (2, 8, 32))
-    out, aux = L.moe(c, p, x)
-    dense = L.swiglu(
-        {
-            "w_gate": p["experts"]["w_gate"][0],
-            "w_up": p["experts"]["w_up"][0],
-            "w_down": p["experts"]["w_down"][0],
-        },
-        x,
+    specs, state_specs = abstract_params(a)
+    params, state = jax.jit(lambda k0, k1: (init_tree(k0, specs), init_tree(k1, state_specs)))(
+        jax.random.key(0), jax.random.key(1)
     )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), rtol=1e-5, atol=1e-5)
+    x = jax.random.normal(jax.random.key(2), (2, 32, 32, 3))
+    y = jnp.asarray([1, 3])
+
+    logits, same = jax.jit(lambda p, s, x: classifier_forward(a, p, s, x, train=False))(
+        params, state, x
+    )
+    assert logits.shape == (2, a.cfg.n_classes) and logits.dtype == jnp.float32
+    assert _all_finite(logits), f"{arch_name}: non-finite eval logits"
+    assert jax.tree.structure(same) == jax.tree.structure(state)
+
+    def loss(p, s):
+        out, new_state = classifier_forward(a, p, s, x, train=True)
+        logp = jax.nn.log_softmax(out, -1)
+        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1)), new_state
+
+    (value, new_state), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params, state)
+    assert float(value) > 0
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    assert jax.tree.structure(new_state) == jax.tree.structure(state)
+    assert _all_finite((grads, new_state)), f"{arch_name}: non-finite training step"
+    assert any(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
+
+
+def test_configs_get_unknown_name_lists_the_served_configs():
+    with pytest.raises(KeyError) as err:
+        configs.get("qwen3-0.6b")
+    for name in ("resnet-50", "squeezenet", "swin-b"):
+        assert name in str(err.value)
 
 
 @pytest.mark.slow
 def test_quant_variant_close_but_not_equal():
     from repro import quant
-    from repro.arch import classifier_forward
-    from repro.arch import abstract_params as ap
-    from repro.models.common import init_tree
 
     a = configs.get("resnet-50", smoke=True)
-    specs, st_specs = ap(a)
+    specs, st_specs = abstract_params(a)
     params = init_tree(jax.random.key(0), specs)
     state = init_tree(jax.random.key(1), st_specs)
     qparams, stats = quant.npu_variant(params)
@@ -156,29 +76,3 @@ def test_quant_variant_close_but_not_equal():
     assert not bool(jnp.allclose(lo_fp, lo_q))  # quantization does something
     rel = float(jnp.linalg.norm(lo_fp - lo_q) / jnp.maximum(jnp.linalg.norm(lo_fp), 1e-9))
     assert rel < 0.5
-
-
-def test_int8_kv_cache_decode_close_to_fp():
-    """Cell D (EXPERIMENTS §Perf): int8 KV decode tracks bf16 KV decode with
-    ~1% logit error and identical top-1s on the smoke model."""
-    import dataclasses as dc
-
-    from repro.models import lm
-    from repro.models.common import init_tree
-
-    a = configs.get("qwen3-0.6b", smoke=True)
-    cfg = a.cfg
-    cfgq = dc.replace(cfg, kv_quant=True)
-    params = init_tree(jax.random.key(0), lm.abstract_params(cfg))
-    tokens = jax.random.randint(jax.random.key(1), (2, 10), 0, cfg.vocab)
-    c_fp = lm.make_cache(cfg, 2, 12)
-    c_q = lm.make_cache(cfgq, 2, 12)
-    step_fp = jax.jit(lambda p, t, c: lm.decode_step(cfg, p, t, c))
-    step_q = jax.jit(lambda p, t, c: lm.decode_step(cfgq, p, t, c))
-    lf = lq = None
-    for s in range(10):
-        lf, c_fp = step_fp(params, tokens[:, s : s + 1], c_fp)
-        lq, c_q = step_q(params, tokens[:, s : s + 1], c_q)
-    rel = float(jnp.linalg.norm(lf - lq) / jnp.linalg.norm(lf))
-    assert 0 < rel < 0.05  # real but small quantization error
-    assert bool(jnp.all(jnp.argmax(lf, -1) == jnp.argmax(lq, -1)))

@@ -256,3 +256,19 @@ def test_later_rtt_samples_blend_by_ewma():
     est.observe_rtt(0.1)
     assert est.state().rtt == pytest.approx(0.7 * 0.2 + 0.3 * 0.1)
     assert est.rtt_samples == 2
+
+
+@pytest.mark.parametrize("name", sorted(ONLINE_PARAMS))
+def test_online_smoke_grid_mobility_square(name):
+    """The adaptivity benchmark's smoke cells: 60 frames of the scenario
+    generator's mobility square wave, a 10-point deadline x rtt grid inside
+    one shape bucket at 30 fps."""
+    from repro.scenariogen import make_trace
+
+    base, _ = ONLINE_PARAMS[name]
+    spec = _spec(name, base, make_trace("mobility_square"), n_frames=60)
+    grid = SweepGrid(deadline_ms=(200.0, 208.0, 216.0, 224.0, 232.0), rtt_ms=(50.0, 80.0))
+    ref = Session(spec).run_sweep(grid, backend="reference", mode="online")
+    bat = Session(spec).run_sweep(grid, backend="batched", mode="online")
+    assert bat.backend == "batched" and bat.meta["engine"] == "sim_online_batch"
+    _assert_online_equal(ref, bat)

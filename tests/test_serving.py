@@ -1,7 +1,8 @@
 """Serving-loop regressions: the estimator-echo fix (a wrong bandwidth belief
 must converge to the TRUE link during ``VideoServer.run``), frame degradation,
 the matmul-backend hook that routes convolutions through ``kernels/npu_matmul``,
-and the measured-profile calibration pipeline."""
+the measured-profile calibration pipeline, and calibrated end-to-end serving
+(every planned frame answered; the estimator converges from a wrong prior)."""
 from __future__ import annotations
 
 import numpy as np
@@ -465,3 +466,120 @@ def test_load_calibration_rejects_foreign_json(tmp_path):
     p.write_text(json.dumps({"schema": "something-else", "models": []}))
     with pytest.raises(ValueError):
         load_calibration(p)
+
+
+# ---------------------------------------------------------------------------
+# Calibrated end-to-end serving: one smoke calibration, shared by the tests
+# below, trains both classifiers and measures their int8 and edge variants;
+# VideoServer + EdgeBatchServer then serve 48 synthetic frames on the
+# measured profiles over a constant 8 Mbps link.
+# ---------------------------------------------------------------------------
+
+SERVE_FRAMES = 48
+TRUE_MBPS = 8.0
+
+
+@pytest.fixture(scope="module")
+def calibration():
+    from repro.serving import CalibrationConfig, calibrate
+
+    return calibrate(CalibrationConfig.smoke())
+
+
+@pytest.fixture(scope="module")
+def deployment(calibration):
+    """The calibrated models' edge endpoints (``max_batch`` 16, every bucket
+    warmed once for the module) and the frames every run serves."""
+    from repro.serving import BatchedEndpoint, make_synthetic_video
+
+    cfg = calibration.artifact["config"]
+    frames, labels = make_synthetic_video(SERVE_FRAMES, n_classes=cfg["n_classes"],
+                                          res=cfg["res"], seed=cfg["seed"])
+    batched = {
+        j: BatchedEndpoint(
+            f"{cm.payload['name']}-edge-batch",
+            lambda x, p=cm.params, f=cm.forward: f(p, x),
+            max_batch=16,
+        )
+        for j, cm in enumerate(calibration.models)
+    }
+    for ep in batched.values():
+        ep.warmup(frames[0])
+    return calibration, batched, frames, labels
+
+
+def _serve(deployment, policy, *, init_bps):
+    """One ``VideoServer.run`` of ``policy`` over the deployment's frames on
+    the true 8 Mbps link: ``(summary, server, controller, planned)``, where
+    ``planned`` holds every frame a round plan scheduled for processing."""
+    from repro.serving import EdgeBatchServer, VideoServer
+    from repro.session import TraceSpec, _model_from_json
+
+    cal, batched, frames, labels = deployment
+    stream = StreamSpec()
+    trace = TraceSpec(mbps=TRUE_MBPS).build()
+    controller = OnlineController(
+        models=[_model_from_json(cm.payload) for cm in cal.models],
+        stream=stream,
+        policy=PolicySpec.coerce(policy),
+        estimator=BandwidthEstimator(init_bps=init_bps),
+    )
+    controller.estimator.observe_rtt(trace.at(0.0).rtt)
+    server = VideoServer(
+        controller=controller,
+        npu_endpoints={j: cm.npu_endpoint for j, cm in enumerate(cal.models)},
+        stream=stream,
+        trace=trace,
+        edge_server=EdgeBatchServer(batched),
+    )
+    planned: list[int] = []
+    next_plan = controller.next_plan
+
+    def recording_next_plan(head):
+        plan = next_plan(head)
+        planned.extend(head + d.frame for d in plan.decisions
+                       if d.is_processed() and head + d.frame < len(frames))
+        return plan
+
+    controller.next_plan = recording_next_plan
+    summary = server.run(frames, labels)
+    return summary, server, controller, planned
+
+
+@pytest.mark.parametrize("policy", ["max_accuracy", "offload", "local"])
+def test_calibrated_serving_answers_every_planned_frame(deployment, policy):
+    summary, server, _, planned = _serve(deployment, policy, init_bps=TRUE_MBPS * 1e6)
+    assert planned
+    assert sorted(r.frame for r in server.results) == sorted(planned)
+    assert summary["frames"] == len(planned)
+    assert np.isfinite(summary["fps_sustained"]) and summary["fps_sustained"] > 0
+    if policy == "local":
+        assert summary["edge_frames"] == 0
+    if policy == "offload":
+        assert summary["npu_frames"] == 0
+
+
+def test_calibrated_serving_estimator_converges_from_wrong_prior(deployment):
+    """Offload sends every frame, so the estimator sees one measured transfer
+    per frame: started 10x high, it must end within 25% of the true link's
+    bandwidth times its pessimism, on at least 8 samples."""
+    true_bps = TRUE_MBPS * 1e6
+    _, _, controller, _ = _serve(deployment, "offload", init_bps=10 * true_bps)
+    est = controller.estimator
+    target = true_bps * est.pessimism
+    assert est.samples >= 8
+    assert abs(est.state().bandwidth_bps - target) / target <= 0.25
+
+
+@pytest.mark.parametrize("name", ["resnet-50", "squeezenet"])
+def test_train_classifier_lowers_its_loss(calibration, name):
+    """``train.optim`` drives a served classifier: after the smoke budget the
+    training loss the calibration recorded is well below the first step's."""
+    from repro.serving.calibrate import train_classifier
+
+    cfg = calibration.artifact["config"]
+    (payload,) = [m for m in calibration.artifact["models"] if m["name"] == name]
+    last = payload["provenance"]["final_loss"]
+    first = train_classifier(name, n_classes=cfg["n_classes"], res=cfg["res"],
+                             seed=cfg["seed"], steps=1)[-1]
+    assert last < 0.9 * first, (first, last)

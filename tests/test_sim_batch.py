@@ -479,3 +479,35 @@ def test_sweep_cli_smoke(tmp_path, capsys, compile_cache_dir):
     ]) == 2
     err = capsys.readouterr().err
     assert err.startswith("error:") and "Traceback" not in err
+
+
+# The sweep benchmark's smoke cells: 10-point grids at 120 frames.  The
+# local-only jax_* planners sweep deadline x fps over a constant 2.5 Mbps
+# link; the network-aware planners sweep deadline x rtt over the piecewise
+# trace.  Equivalence is the certified contract, AUDIT_TOL for all four.
+SMOKE_FRAMES = 120
+SMOKE_CELLS = {
+    "jax_accuracy": ({}, "jax"),
+    "jax_utility": ({"alpha": 200.0}, "jax"),
+    "max_accuracy": ({}, "network"),
+    "max_utility": ({"alpha": 200.0}, "network"),
+}
+
+
+def _smoke_grid(ladder: str) -> tuple[SweepGrid, TraceSpec]:
+    deadlines = (150.0, 200.0, 250.0, 300.0, 350.0)
+    if ladder == "jax":
+        return SweepGrid(deadline_ms=deadlines, fps=(20.0, 40.0)), TraceSpec(mbps=2.5)
+    return SweepGrid(deadline_ms=deadlines, rtt_ms=(50.0, 100.0)), PIECEWISE
+
+
+@pytest.mark.parametrize("name", sorted(SMOKE_CELLS))
+def test_smoke_grid_batched_matches_reference(name):
+    params, ladder = SMOKE_CELLS[name]
+    grid, trace = _smoke_grid(ladder)
+    assert len(grid) == 10
+    spec = ScenarioSpec(policy=PolicySpec(name, params), n_frames=SMOKE_FRAMES, trace=trace)
+    ref = Session(spec).run_sweep(grid, backend="reference")
+    bat = Session(spec).run_sweep(grid, backend="batched")
+    assert bat.backend == "batched"
+    _assert_points_equal(ref, bat, acc_tol=AUDIT_TOL)

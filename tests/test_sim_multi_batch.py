@@ -454,3 +454,35 @@ def test_simulate_multi_audits_bad_plans_through_structured_errors():
     ms2 = simulate_multi(sched2, Trace.constant(6.0), 5, strict=False)
     assert ms2.per_client[0].frames_missed_deadline == 0
     assert ms2.per_client[0].frames_processed == 5
+
+
+# ---------------------------------------------------------------------------
+# The fleet benchmark's smoke ladders at 10 points: a bandwidth x
+# allocation grid of interacting fleets on a 6 Mbps link with 4 server
+# slots.  Offload runs 30 frames over fleets of 4; the max_accuracy DP
+# runs 12 frames on a 10 ms grid over fleets of 2.
+# ---------------------------------------------------------------------------
+
+LADDERS = {
+    "offload": ({}, 30, 4),
+    "max_accuracy": ({"grid": 10e-3}, 12, 2),
+}
+
+
+@pytest.mark.parametrize("policy", sorted(LADDERS))
+def test_ladder_grid_batched_matches_reference(policy):
+    params, frames, n_clients = LADDERS[policy]
+    grid = SweepGrid(bandwidth_mbps=(2.0, 4.0, 6.0, 9.0, 12.0), n_clients=(n_clients,),
+                     allocation=("weighted_fair", "fifo"))
+    session = Session(
+        ScenarioSpec(
+            policy=PolicySpec(policy, params),
+            n_frames=frames,
+            trace=TraceSpec(mbps=6.0),
+            fleet=FleetSpec(capacity=4),
+        )
+    )
+    ref = session.run_sweep(grid, backend="reference")
+    bat = session.run_sweep(grid, backend="batched")
+    assert bat.backend == "batched" and bat.meta["engine"] == "sim_multi_batch"
+    _assert_fleet_reports_equal(ref, bat)

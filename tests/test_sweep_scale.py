@@ -256,7 +256,7 @@ from repro.session import ScenarioSpec, Session, SweepGrid
 
 import jax
 assert jax.device_count() == 4
-from repro.launch.mesh import make_sweep_mesh
+from repro.core.sweep_shard import make_sweep_mesh
 assert make_sweep_mesh().size == 4
 
 spec = ScenarioSpec(policy=PolicySpec("jax_accuracy"), n_frames=12)

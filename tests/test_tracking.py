@@ -348,3 +348,40 @@ def test_track_state_carries_across_rounds():
     assert stats2.accuracy_sum == pytest.approx(
         state.det_acc * wl.retention**3, abs=0
     )
+
+
+# ---------------------------------------------------------------------------
+# The tracking benchmark's smoke cells: a 10-point deadline x rtt grid over a
+# piecewise trace at 120 frames, both planners single-stream and
+# track_accuracy on a 3-client fleet (2 server slots); ints exact, accuracy
+# within MULTI_TOL.
+# ---------------------------------------------------------------------------
+
+SMOKE_WORKLOAD = WorkloadSpec("track", decay=0.2, density=1.0)
+SMOKE_TRACE = TraceSpec(kind="piecewise", points=((0.0, 3.0), (0.3, 0.8), (0.9, 6.0)),
+                        rtt_ms=60.0)
+SMOKE_CELLS = {
+    "track_accuracy": ("track_accuracy", {"decay": 0.2, "k_max": 6}, None),
+    "track_fixed": ("track_fixed", {"k": 3}, None),
+    "track_accuracy_fleet": ("track_accuracy", {"decay": 0.2, "k_max": 6},
+                             FleetSpec(n_clients=3, capacity=2)),
+}
+
+
+@pytest.mark.parametrize("cell", list(SMOKE_CELLS))
+def test_smoke_grid_batched_matches_reference(cell):
+    policy, params, fleet = SMOKE_CELLS[cell]
+    grid = SweepGrid(deadline_ms=(150.0, 200.0, 250.0, 300.0, 350.0), rtt_ms=(50.0, 100.0))
+    session = Session(
+        ScenarioSpec(policy=PolicySpec(policy, params), n_frames=120, trace=SMOKE_TRACE,
+                     workload=SMOKE_WORKLOAD, fleet=fleet)
+    )
+    ref = session.run_sweep(grid, backend="reference")
+    bat = session.run_sweep(grid, backend="batched")
+    assert bat.backend == "batched"
+    assert len(ref.points) == len(bat.points) == 10
+    for pr, pb in zip(ref.points, bat.points):
+        assert pr.overrides == pb.overrides
+        assert len(pr.streams) == len(pb.streams) == (3 if fleet else 1)
+        for sr, sb in zip(pr.streams, pb.streams):
+            _assert_stats_equal(sr, sb, MULTI_TOL)
